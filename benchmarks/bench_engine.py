@@ -1,60 +1,31 @@
-"""Serving-substrate integration: batched engine throughput (reduced model
-on CPU) and B-PASTE batch-slot speculation hit behavior — the paper's
-technique running against real model decode steps."""
+"""Serving-substrate integration: agent episodes through the one serving
+loop (``repro.launch.serve``) on a reduced model, with B-PASTE batch-slot
+speculation.  Rows name the platform they ran on; a CPU row times the CPU
+backend, not a device."""
 from __future__ import annotations
 
-import time
 from typing import Dict, List
 
 import jax
 
-from repro.configs import get_config
-from repro.core.events import DEFAULT_TOOLS
-from repro.core.hypothesis import BranchHypothesis, Node, NodeKind
-from repro.models import model as model_mod
-from repro.serving.engine import ServingEngine
-from repro.serving.spec_serving import SlotSpeculator, render_observation
+from repro.launch import serve
 
 
 def run() -> List[Dict]:
-    rows = []
-    cfg = get_config("musicgen-medium").reduced()
-    params = model_mod.init_params(jax.random.key(0), cfg)
-    eng = ServingEngine(cfg, params, max_batch=4, max_len=128)
-    eng.add_request([2, 3, 4], request_id=0)
-    eng.step()  # warm jit
-    t0 = time.perf_counter()
-    n = 30
-    for _ in range(n):
-        eng.step()
-    dt = (time.perf_counter() - t0) / n
-    rows.append({"name": "serving/decode_step_b4", "us_per_call": dt * 1e6,
-                 "derived": f"steps/s={1/dt:.1f} (reduced model, CPU)"})
-
-    # prefill-into-slot latency
-    t0 = time.perf_counter()
-    slot = eng.add_request([5, 6, 7, 8, 9], request_id=1)
-    dt = time.perf_counter() - t0
-    rows.append({"name": "serving/prefill_into_slot", "us_per_call": dt * 1e6,
-                 "derived": "includes slot cache write"})
-
-    # speculation promote path
-    for s in eng.slots:
-        s.active = False
-    spec = SlotSpeculator(eng, budget_slots=2)
-    n_spec = DEFAULT_TOOLS["search"]
-    h = BranchHypothesis(1, [Node(0, NodeKind.TOOL, "search", n_spec.level,
-                                  n_spec.rho, 1.0)], [], q=0.9, context_key=())
-    t0 = time.perf_counter()
-    spec.admit([(h, 1.0)], history_prompt=[2, 3])
-    for _ in range(5):
-        eng.step()
-    obs = render_observation("search", {}, "pred:1:0", cfg.vocab_size)
-    got = spec.match_and_promote(obs, request_id=7)
-    dt = time.perf_counter() - t0
-    rows.append({
-        "name": "serving/speculate_admit_promote",
-        "us_per_call": dt * 1e6,
-        "derived": f"promoted={got is not None} (5 spec decode steps already done at promotion)",
-    })
-    return rows
+    max_batch = 4
+    _, r = serve.run("granite-8b", reduced=True, n_episodes=3,
+                     max_batch=max_batch, max_len=192, prompt_len=48,
+                     reason_tokens=5)
+    dev = jax.devices()[0]
+    where = f"reduced model, {dev.platform} {dev.device_kind}"
+    return [
+        {"name": f"serving/decode_step_b{max_batch}",
+         "us_per_call": r.decode_step_s * 1e6,
+         "derived": f"median over {r.decode_steps} steps, "
+                    f"{r.tokens_decoded} tokens ({where})"},
+        {"name": "serving/compile", "us_per_call": r.compile_s * 1e6,
+         "derived": f"decode + {len(r.compiled) - 1} prefill lengths ({where})"},
+        {"name": "serving/speculative_slots", "us_per_call": r.serve_s * 1e6,
+         "derived": f"admitted={r.spec_admitted} promoted={r.promotions} "
+                    f"preempted={r.preemptions} (whole loop, {where})"},
+    ]

@@ -16,17 +16,17 @@ speculation + cross-episode result store convert the recovered slack into
 end-to-end speedup — while ``mean_auth_slowdown`` stays at 1.0 and QoS
 violations stay at zero (batching never taxes the authoritative path).
 
-With ``--with-llm``, additionally runs a real (reduced-config) LLM on the
-ServingEngine with B-PASTE batch-slot speculation: the agent loop decodes
-reasoning tokens on the engine; during each tool call, B-PASTE prefills
-the predicted observation into a free slot so the follow-up reasoning is
+With ``--with-llm``, additionally serves agent episodes on a real
+(reduced-config) LLM through ``repro.launch.serve``, once without and once
+with B-PASTE batch-slot speculation: the agent loop decodes reasoning
+tokens on the ServingEngine; during each tool call, B-PASTE prefills the
+predicted observation into a free slot so the follow-up reasoning is
 already decoding when the tool returns (promotion = zero-copy slot
-re-tag).  This path compiles a JAX model and takes minutes on CPU.
+re-tag).  This path compiles a JAX model.
 
   PYTHONPATH=src python examples/speculative_serving.py --with-llm --arch qwen2-7b
 """
 import argparse
-import time
 
 
 # ----------------------------------------------------------------------
@@ -139,70 +139,17 @@ def run_open_loop_demo(rate: float = 0.1, n_episodes: int = 16,
 # ----------------------------------------------------------------------
 # Part 2 (--with-llm): batch-slot speculation on a real reduced LLM
 # ----------------------------------------------------------------------
-def serve(spec_on: bool, cfg, params, episodes, pattern_engine, reason_tokens=5):
-    from repro.core.events import Event
-    from repro.core.hypothesis import HypothesisBuilder
-    from repro.serving.engine import ServingEngine
-    from repro.serving.spec_serving import SlotSpeculator, render_observation
-
-    eng = ServingEngine(cfg, params, max_batch=4, max_len=192)
-    spec = SlotSpeculator(eng, budget_slots=2)
-    builder = HypothesisBuilder(pattern_engine)
-    decode_steps = 0
-    hits = 0
-    t0 = time.time()
-    for ep in episodes:
-        history = []
-        prompt = [2, 3, 4]
-        slot = eng.add_request(prompt, request_id=ep.eid)
-        for step in ep.steps[:4]:
-            # reasoning: decode a few tokens on the authoritative slot
-            for _ in range(reason_tokens):
-                eng.step()
-                decode_steps += 1
-            # while the tool "runs", speculate likely continuations
-            if spec_on and history:
-                hyps = builder.build(history, beam_width=3)
-                spec.admit([(h, h.q) for h in hyps], history_prompt=prompt)
-                for _ in range(3):          # tool latency window
-                    eng.step()
-                    decode_steps += 1
-            obs = render_observation(step.tool, {}, f"pred:{step.tool}", cfg.vocab_size)
-            got = spec.match_and_promote(obs, ep.eid) if spec_on else None
-            if got is not None:
-                hits += 1
-            history.append(Event("tool", step.tool, dict(step.args), {"ok": True}))
-        spec.squash_all()
-        for s in eng.slots:
-            s.active = False
-            s.request_id = None
-    return time.time() - t0, decode_steps, hits, spec
-
-
 def run_llm_demo(arch: str, n_episodes: int) -> None:
-    import jax
+    from repro.launch import serve
 
-    from repro.configs import get_config
-    from repro.core.patterns import PatternEngine
-    from repro.core.workload import (
-        WorkloadConfig, episodes_to_traces, make_episodes,
-    )
-    from repro.models import model as model_mod
-
-    cfg = get_config(arch).reduced()
-    params = model_mod.init_params(jax.random.key(0), cfg)
-    history = make_episodes(WorkloadConfig(seed=1, n_episodes=40))
-    pe = PatternEngine(context_len=2, min_support=3).fit(episodes_to_traces(history))
-    episodes = make_episodes(WorkloadConfig(seed=9, n_episodes=n_episodes))
-
-    dt0, steps0, _, _ = serve(False, cfg, params, episodes, pe)
-    dt1, steps1, hits, spec = serve(True, cfg, params, episodes, pe)
-    print(f"baseline : {steps0} decode steps in {dt0:.1f}s")
-    print(f"B-PASTE  : {steps1} decode steps in {dt1:.1f}s "
-          f"(speculative slots admitted={spec.admitted}, promoted={spec.promotions}, "
-          f"preempted={spec.preemptions})")
-    print("promoted slots had their follow-up reasoning already decoded -> "
-          "the tool-return -> next-action latency is hidden")
+    sizes = dict(reduced=True, n_episodes=n_episodes, max_batch=4,
+                 max_len=192, prompt_len=48, reason_tokens=5)
+    _, base = serve.run(arch, speculate=False, **sizes)
+    _, spec = serve.run(arch, speculate=True, **sizes)
+    print(f"baseline : {base.decode_steps} decode steps in {base.serve_s:.1f}s")
+    print(f"B-PASTE  : {spec.decode_steps} decode steps in {spec.serve_s:.1f}s "
+          f"(speculative slots admitted={spec.spec_admitted}, "
+          f"promoted={spec.promotions}, preempted={spec.preemptions})")
 
 
 def main():

@@ -1,52 +1,33 @@
-"""JAX version-compatibility shims.
+"""The one place the code base builds meshes, ``shard_map``s and Pallas-TPU
+compiler params (staticcheck rule C3 routes every such call through here).
 
-The codebase targets the modern jax API surface (top-level
-``jax.shard_map`` with ``check_vma``, ``jax.sharding.AxisType`` meshes);
-the baked-in toolchain may ship an older jax (0.4.x) where ``shard_map``
-lives in ``jax.experimental.shard_map`` (with ``check_rep``) and
-``jax.make_mesh`` has no ``axis_types``.  Every shard_map/mesh call site
-goes through these helpers so the repo runs unmodified on both.
+Written for the installed jax 0.9: ``jax.make_mesh`` with explicit
+``AxisType.Auto`` axes, top-level ``jax.shard_map`` and
+``pltpu.CompilerParams``.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
-
-try:                                       # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:                        # jax 0.4.x
-    _AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence] = None):
-    """``jax.make_mesh`` with Auto axis types where supported."""
+    """``jax.make_mesh`` with Auto axis types."""
     kw = {"devices": devices} if devices is not None else {}
-    if _AxisType is not None:
-        kw["axis_types"] = (_AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names), **kw)
 
 
 def pltpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params: ``pltpu.CompilerParams`` (new) /
-    ``pltpu.TPUCompilerParams`` (0.4.x) — same kwargs."""
+    """Pallas-TPU compiler params."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
-if hasattr(jax, "shard_map"):              # jax >= 0.6
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        """``jax.shard_map`` with replication checking off (both APIs)."""
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        """``jax.shard_map`` with replication checking off (both APIs)."""
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

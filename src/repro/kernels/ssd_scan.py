@@ -14,6 +14,9 @@ Per (b, h, c) the kernel computes, entirely in VMEM:
                   Y_intra = scores @ X                      (Q,P)
   * state update: S = exp(cs[Q−1])·S_prev + (decay_to_end·dt·B)ᵀ @ X
 Chunk Q defaults to 128 (MXU-aligned); head_dim P and state N are 64/128.
+The per-head scalars A and D ride whole in SMEM, indexed by the head id: a
+(1, 1) VMEM block of an (H, 1) array breaks the TPU tiling rule (a block's
+last two dims must be divisible by (8, 128) or equal the array's).
 """
 from __future__ import annotations
 
@@ -29,11 +32,12 @@ from repro import compat
 
 
 def _kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
+    x_ref, dt_ref, dt_row_ref, a_ref, b_ref, c_ref, d_ref, h0_ref,
     y_ref, hout_ref,
     state_scr,
     *, chunk: int, use_h0: bool,
 ):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -45,30 +49,37 @@ def _kernel(
             state_scr[...] = jnp.zeros_like(state_scr)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)      # (Q, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)[:, 0]  # (Q,)
-    a = a_ref[0, 0]                             # scalar
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)    # (Q, 1)
+    dt_row = dt_row_ref[0, 0, 0].astype(jnp.float32)  # (1, Q)
+    a = a_ref[hi]                               # scalar (SMEM)
     bmat = b_ref[0, 0, 0].astype(jnp.float32)   # (Q, N)
     cmat = c_ref[0, 0, 0].astype(jnp.float32)   # (Q, N)
-    dcoef = d_ref[0, 0]                         # scalar
+    dcoef = d_ref[hi]                           # scalar (SMEM)
 
-    da = dt * a                                 # (Q,)
-    cs = jnp.cumsum(da)                         # inclusive (Q,)
+    # Mosaic has no cumsum: the inclusive prefix sum of dt*A is a matmul
+    # with a triangular ones matrix, once per layout (column and row), all
+    # kept 2-D.  HIGHEST keeps the sum in f32 on the MXU.
+    iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = iota_i >= iota_j
+    tri = causal.astype(jnp.float32)
+    hp = jax.lax.Precision.HIGHEST
+    da = dt * a                                 # (Q, 1)
+    cs = jnp.dot(tri, da, precision=hp)         # (Q, 1) inclusive cumsum
+    cs_row = jnp.dot(dt_row * a, tri.T, precision=hp)   # (1, Q)
+    total = jnp.sum(da, axis=0, keepdims=True)  # (1, 1) = cs[Q-1]
 
     s_prev = state_scr[...]                     # (N, P)
     # inter-chunk contribution
-    y_inter = jnp.exp(cs)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(cs) * jax.lax.dot_general(
         cmat, s_prev, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                           # (Q, P)
-    # intra-chunk quadratic part
-    li = cs[:, None]
-    lj = cs[None, :]
-    iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    lmat = jnp.where(iota_i >= iota_j, jnp.exp(li - lj), 0.0)
+    # intra-chunk quadratic part: L[i,j] = exp(cs[i]-cs[j]) for i>=j else 0
+    lmat = jnp.where(causal, jnp.exp(cs - cs_row), 0.0)
     cb = jax.lax.dot_general(
         cmat, bmat, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                           # (Q, Q)
-    scores = cb * lmat * dt[None, :]
+    scores = cb * lmat * dt_row
     y = y_inter + jax.lax.dot_general(
         scores, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -76,9 +87,9 @@ def _kernel(
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
     # state update: S_new = exp(cs[-1]) * S_prev + sum_j w_j * B_j (outer) X_j
-    w = jnp.exp(cs[-1] - cs) * dt               # (Q,)
-    s_new = jnp.exp(cs[-1]) * s_prev + jax.lax.dot_general(
-        bmat * w[:, None], x, (((0,), (0,)), ((), ())),
+    w = jnp.exp(total - cs) * dt                # (Q, 1)
+    s_new = jnp.exp(total) * s_prev + jax.lax.dot_general(
+        bmat * w, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                           # (N, P)
     state_scr[...] = s_new
@@ -107,10 +118,11 @@ def ssd_scan_pallas(
     # layouts: per-(b,h) tiles
     xt = jnp.moveaxis(pad_seq(x), 2, 1).reshape(b, h, nc, chunk, p)
     dtt = jnp.moveaxis(pad_seq(dt), 2, 1).reshape(b, h, nc, chunk, 1)
+    dtt_row = dtt.reshape(b, h, nc, 1, chunk)
     bt = jnp.repeat(jnp.moveaxis(pad_seq(B), 2, 1), reps, axis=1).reshape(b, h, nc, chunk, n)
     ct = jnp.repeat(jnp.moveaxis(pad_seq(C), 2, 1), reps, axis=1).reshape(b, h, nc, chunk, n)
-    a2 = A.reshape(h, 1).astype(jnp.float32)
-    d2 = D.reshape(h, 1).astype(jnp.float32)
+    a2 = A.astype(jnp.float32)
+    d2 = D.astype(jnp.float32)
     use_h0 = initial_state is not None
     h0 = (
         initial_state.transpose(0, 1, 3, 2).astype(jnp.float32)  # (B,H,N,P)
@@ -119,16 +131,18 @@ def ssd_scan_pallas(
     )
 
     grid = (b, h, nc)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     y, hout = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk, use_h0=use_h0),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, p), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, 1), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
-            pl.BlockSpec((1, 1), lambda bi, hi, ci: (hi, 0)),
+            pl.BlockSpec((1, 1, 1, 1, chunk), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
+            smem,
             pl.BlockSpec((1, 1, 1, chunk, n), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, n), lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
-            pl.BlockSpec((1, 1), lambda bi, hi, ci: (hi, 0)),
+            smem,
             pl.BlockSpec((1, 1, n, p), lambda bi, hi, ci: (bi, hi, 0, 0)),
         ],
         out_specs=[
@@ -144,7 +158,7 @@ def ssd_scan_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(xt, dtt, a2, bt, ct, d2, h0)
+    )(xt, dtt, dtt_row, a2, bt, ct, d2, h0)
     y = y.reshape(b, h, nc * chunk, p)[:, :, :s]
     y = jnp.moveaxis(y, 1, 2)                    # (B,S,H,P)
     return y.astype(x.dtype), hout.transpose(0, 1, 3, 2)  # (B,H,P,N)

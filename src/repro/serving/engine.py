@@ -9,9 +9,8 @@ authoritative — zero-copy, KV rows are slot-stable.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -105,19 +104,37 @@ class ServingEngine:
                 lambda new, old, ax: _mask_batch(new, old, active_mask, ax),
                 new_cache, cache, self._batch_axes,
             )
-            return nxt, new_cache
+            return nxt, logits, new_cache
 
         self._decode = _decode
 
-        @functools.partial(jax.jit, static_argnames=("prompt_len",))
-        def _prefill_one(params, tokens, prompt_len: int):
+        @jax.jit
+        def _prefill_one(params, tokens):
+            # compiles once per prompt length (the shape of `tokens`)
             logits, cache1 = model_mod.prefill(
                 params, cfg, {"tokens": tokens}, max_len, mesh_info
             )
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, cache1
+            return nxt, logits, cache1
 
         self._prefill_one = _prefill_one
+        # logits of the last prefill (1, Vp) or decode step (max_batch, Vp),
+        # left on the device; read only by correctness checks
+        self.last_logits = None
+
+    # ------------------------------------------------------------------
+    def compile(self, prompt_lens: Sequence[int]) -> Dict[str, Any]:
+        """Compile the decode step and one prefill per prompt length ahead
+        of serving; later calls reuse these programs.  Returns the compiled
+        programs, keyed ``decode`` and ``prefill_<len>``."""
+        b = self.max_batch
+        out = {"decode": self._decode.lower(
+            self.params, self.cache, jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.bool_)).compile()}
+        for n in sorted(set(prompt_lens)):
+            out[f"prefill_{n}"] = self._prefill_one.lower(
+                self.params, jax.ShapeDtypeStruct((1, n), jnp.int32)).compile()
+        return out
 
     # ------------------------------------------------------------------
     def free_slots(self) -> List[int]:
@@ -143,7 +160,7 @@ class ServingEngine:
         s.active = True
         s.done = False
         toks = jnp.asarray([prompt], jnp.int32)
-        nxt, cache1 = self._prefill_one(self.params, toks, len(prompt))
+        nxt, self.last_logits, cache1 = self._prefill_one(self.params, toks)
         self.cache = _write_slot(self.cache, cache1, slot)
         self.pending_tokens[slot] = int(nxt[0])
         return slot
@@ -168,7 +185,8 @@ class ServingEngine:
         if not active.any():
             return {}
         tokens = jnp.asarray(self.pending_tokens, jnp.int32)
-        nxt, self.cache = self._decode(self.params, self.cache, tokens, jnp.asarray(active))
+        nxt, self.last_logits, self.cache = self._decode(
+            self.params, self.cache, tokens, jnp.asarray(active))
         nxt = np.asarray(nxt)
         out: Dict[int, int] = {}
         self.steps_executed += 1

@@ -32,7 +32,7 @@ AST-level static guarantee, checked in CI on every push:
                 (they are static under tracing).
   C3-compat     compat-bypass: direct ``Mesh``/``shard_map``/``pltpu``
                 compiler-param usage outside ``repro/compat.py`` (the
-                ROADMAP version-shim rule, previously unenforced).
+                one layer where those calls live).
   C4-dispatch   dispatch-shape discipline: ``pack_beam`` calls whose k
                 argument doesn't flow through ``bucket_k`` (or the
                 ``k_max`` cap it buckets to), and calls into the jitted
@@ -751,7 +751,7 @@ def _check_compat(relpath: str, tree: ast.Module,
         flagged.add((lineno, what))
         _emit(report, "C3-compat", "error", site,
               f"line {lineno}: {what} — route through repro.compat "
-              f"(the jax 0.4.x/modern shim layer)")
+              f"(the one mesh/shard_map/Pallas-params layer)")
 
     for n in ast.walk(tree):
         if isinstance(n, ast.ImportFrom):

@@ -73,7 +73,7 @@ def test_compressed_sync_multidevice():
         assert np.allclose(np.asarray(synced), np.asarray(synced)[0], atol=1e-6)
         print("COMPRESS_OK")
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=env, timeout=300,
                        cwd=os.path.dirname(os.path.dirname(__file__)) or ".")

@@ -75,7 +75,7 @@ def test_elastic_restore_across_meshes(tmp_path):
         np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(w))
         print("ELASTIC_OK")
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, cwd=os.path.dirname(os.path.dirname(__file__)) or ".",
                        env=env, timeout=300)
@@ -197,3 +197,50 @@ def test_engine_speculative_promote_and_preempt(small_engine):
     spec.admit([(h, 1.0)], history_prompt=[2, 3])
     spec.ensure_authoritative_room(len(eng.free_slots()) + 1)
     assert spec.spec_slots_used() == 0
+
+
+# ======================================================================
+# serving entry point
+# ======================================================================
+
+def test_serve_run_reduced_serves_and_speculates():
+    """The one serving loop, end to end at the toy preset: every episode is
+    served, speculation admits slots, and the prefill compiles exactly for
+    the authoritative and the speculative prompt length."""
+    from repro.launch import serve
+    eng, r = serve.run("granite-8b", reduced=True, n_episodes=2, max_batch=4,
+                       max_len=128, prompt_len=24, reason_tokens=3)
+    assert r.episodes == 2 and r.tokens_decoded >= 2 * 3
+    assert r.spec_admitted > 0 and r.preemptions + r.promotions == r.spec_admitted
+    assert sorted(r.compiled) == ["decode", "prefill_24",
+                                  f"prefill_{24 + serve.OBS_TOKENS}"]
+    assert eng.last_logits.shape == (4, eng.cfg.padded_vocab)
+    assert not any(s.active for s in eng.slots)
+
+
+def test_jitted_init_matches_eager_init():
+    """The serving entry point's jitted init draws the very same weights."""
+    from repro.launch import serve
+    cfg = get_config("granite-8b").reduced()
+    jitted = serve.init_params(cfg, 3)
+    eager = M.init_params(jax.random.key(3), cfg)
+    for a, b in zip(jax.tree.leaves(jitted), jax.tree.leaves(eager), strict=True):
+        assert a.dtype == b.dtype == jnp.dtype(cfg.dtype)
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_compile_cache_dir(monkeypatch):
+    from repro.launch.compile_cache import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert use_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before   # JAX reads the env itself
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = use_compile_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -98,7 +98,7 @@ def test_sharded_decode_multidevice():
         assert err2 < 2e-2, err2
         print("SHARDED_DECODE_OK")
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=env, timeout=420,
                        cwd=os.path.dirname(os.path.dirname(__file__)) or ".")
@@ -129,7 +129,7 @@ def test_fsdp_specs_cover_all_params():
         jax.tree.map(check, structs, specs, is_leaf=lambda x: isinstance(x, P))
         print("FSDP_SPECS_OK")
     """)
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=env, timeout=300,
                        cwd=os.path.dirname(os.path.dirname(__file__)) or ".")
