@@ -1,0 +1,69 @@
+"""A copy of the benchmark with a tiny configuration, mix and metric added as
+files only, for the CPU tests."""
+from __future__ import annotations
+
+import json
+import os
+import shutil
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+TINY_CONFIG = {
+    "name": "tiny", "architecture": "llama", "reference": "llama",
+    "num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
+    "vocab_size": 256, "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
+    "attention_bias": False, "tie_word_embeddings": False, "torch_dtype": "bfloat16",
+    "attn_impl": "pallas_interpret",
+    "engine": {"max_batch": 4, "max_len": 256, "spec_slots": 2},
+    "check": {"logit_gap_limit": 0.02},
+}
+
+TINY_MIX = {
+    "generator": "react_agents", "agents": 6, "rounds": 16, "roster": "roster.json",
+    "initial_tokens": 64, "bucket": 32, "keep_tokens": 96,
+    "reason_tokens": {"median": 5, "sigma": 0.4, "min": 3, "max": 8},
+    "observation_tokens": {"median": 12, "sigma": 0.4, "min": 4, "max": 24},
+    "stagger_s": 0.02, "tool_latency_scale": 0.03,
+    "spec_beam_width": 3, "spec_obs_tokens": 16,
+}
+
+# a per-layer metric that no file of the benchmark has
+NEW_METRIC = '''"""Turns counted in the window."""
+
+
+def read(rec):
+    return float(sum(1 for t in rec["turns"] if t.end is not None))
+'''
+
+
+def tiny_bench(root: str, **config_overrides) -> str:
+    """Copy the benchmark under ``root``, add the tiny files, and return the
+    path of the copy's BENCHMARK.json."""
+    dst = os.path.join(root, "bench")
+    shutil.copytree(BENCH, dst, ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    with open(os.path.join(dst, "configs", "tiny.json"), "w") as f:
+        json.dump(dict(TINY_CONFIG, **config_overrides), f)
+    with open(os.path.join(dst, "traffic", "tiny_mix.json"), "w") as f:
+        json.dump(TINY_MIX, f)
+    with open(os.path.join(dst, "metrics", "turns_finished.py"), "w") as f:
+        f.write(NEW_METRIC)
+    with open(os.path.join(dst, "peaks.json")) as f:
+        peaks = json.load(f)
+    peaks["cpu"] = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11,
+                    "source": "a stand-in for CPU tests; no CPU number is a device metric"}
+    with open(os.path.join(dst, "peaks.json"), "w") as f:
+        json.dump(peaks, f)
+    cell = "tiny.tiny_mix"
+    bench = {
+        "workloads": [{"name": cell, "config": "tiny", "traffic": "tiny_mix", "chips": 1}],
+        "end_to_end": [{"name": n, "unit": u} for n, u in (
+            ("turn_p90_s", "s"), ("ttft_p90_s", "s"), ("itl_p99_ms", "ms"),
+            ("tokens_per_s", "tokens/s"), ("setup_s", "s"))],
+        "per_layer": [{"name": "decode_step_ms", "unit": "ms", "workloads": [cell]},
+                      {"name": "turns_finished", "unit": "turns", "workloads": [cell]}],
+    }
+    path = os.path.join(dst, "BENCHMARK.json")
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    return path
