@@ -140,6 +140,7 @@ def decode_attention_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), qg, kt, vt)
     return out.reshape(b, h, d)
 
@@ -197,5 +198,6 @@ def decode_attention_partials_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_attention_partials",
     )(lengths.astype(jnp.int32), qg, kt, vt)
     return acc, m[..., 0], l[..., 0]

@@ -132,6 +132,7 @@ def flash_attention_pallas(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     out = out[:, :, :s]
     return jnp.moveaxis(out, 1, 2)

@@ -271,15 +271,17 @@ def prefill(
 
         def body(carry, blk):
             h, lb = carry
-            x = rms_norm(h, blk["ln1"], cfg.norm_eps)
-            o, kc, vc = attn_mod.attn_prefill(blk["attn"], x, cfg, smax, impl=impl)
-            h = h + o
-            if cfg.family == "moe":
-                y, aux = moe_mod.moe_forward(blk["moe"], rms_norm(h, blk["ln2"], cfg.norm_eps), cfg, mesh_info)
-                h = h + y
-                lb = lb + aux["lb_loss"]
-            else:
-                h = h + mlp_mod.mlp_forward(blk["mlp"], rms_norm(h, blk["ln2"], cfg.norm_eps))
+            with jax.named_scope("attention"):
+                x = rms_norm(h, blk["ln1"], cfg.norm_eps)
+                o, kc, vc = attn_mod.attn_prefill(blk["attn"], x, cfg, smax, impl=impl)
+                h = h + o
+            with jax.named_scope("mlp"):
+                if cfg.family == "moe":
+                    y, aux = moe_mod.moe_forward(blk["moe"], rms_norm(h, blk["ln2"], cfg.norm_eps), cfg, mesh_info)
+                    h = h + y
+                    lb = lb + aux["lb_loss"]
+                else:
+                    h = h + mlp_mod.mlp_forward(blk["mlp"], rms_norm(h, blk["ln2"], cfg.norm_eps))
             return (h, lb), (kc, vc)
 
         if cfg.remat:
@@ -344,18 +346,24 @@ def decode_step(
     if cfg.family in ("dense", "vlm", "audio", "moe"):
         def body(h, xs):
             blk, kc, vc = xs
-            x = rms_norm(h, blk["ln1"], cfg.norm_eps)
-            o, kc, vc = attn_mod.attn_decode_dispatch(
-                blk["attn"], x, kc, vc, lengths, cfg, mesh_info, impl=impl)
-            h = h + o
-            if cfg.family == "moe":
-                y, _ = moe_mod.moe_forward(blk["moe"], rms_norm(h, blk["ln2"], cfg.norm_eps), cfg, mesh_info)
-                h = h + y
-            else:
-                h = h + mlp_mod.mlp_forward(blk["mlp"], rms_norm(h, blk["ln2"], cfg.norm_eps))
+            with jax.named_scope("attention"):
+                x = rms_norm(h, blk["ln1"], cfg.norm_eps)
+                o, kc, vc = attn_mod.attn_decode_dispatch(
+                    blk["attn"], x, kc, vc, lengths, cfg, mesh_info, impl=impl)
+                h = h + o
+            with jax.named_scope("mlp"):
+                if cfg.family == "moe":
+                    y, _ = moe_mod.moe_forward(blk["moe"], rms_norm(h, blk["ln2"], cfg.norm_eps), cfg, mesh_info)
+                    h = h + y
+                else:
+                    h = h + mlp_mod.mlp_forward(blk["mlp"], rms_norm(h, blk["ln2"], cfg.norm_eps))
             return h, (kc, vc)
 
-        h, (kc, vc) = jax.lax.scan(body, h, (params["blocks"], cache["k"], cache["v"]))
+        # the loop slices each layer's cache out of the stacked cache and
+        # writes it back whole (its ``dynamic_update_slice``): a scope on the
+        # loop names that write-back, the body's scopes name the rest
+        with jax.named_scope("layers"):
+            h, (kc, vc) = jax.lax.scan(body, h, (params["blocks"], cache["k"], cache["v"]))
         new_cache["k"], new_cache["v"] = kc, vc
         return lm_logits(params, cfg, h[:, 0]), new_cache
 

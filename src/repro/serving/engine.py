@@ -6,6 +6,13 @@ resources": B-PASTE admits speculative sequences into them, preempts by
 dropping a slot at the next decode-step boundary (one step = the preemption
 granularity on an accelerator), and promotes by re-tagging a slot
 authoritative — zero-copy, KV rows are slot-stable.
+
+The engine times its own host work with the spans and counters of
+``serving/telemetry.py``: ``engine.prefill`` (with ``.write_slot`` and
+``.wait``) around ``add_request``, ``engine.decode`` (with ``.prep``,
+``.wait`` and ``.emit``) around ``step``, and the counter
+``engine.slot_release`` for the rows a speculative slot decoded, counted
+where the slot is preempted, promoted or ends itself.
 """
 from __future__ import annotations
 
@@ -18,6 +25,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as model_mod
+from repro.serving import telemetry
 
 
 @dataclass
@@ -29,6 +37,7 @@ class Slot:
     tokens: List[int] = field(default_factory=list)
     active: bool = False
     done: bool = False
+    spec_rows: int = 0      # rows decoded while speculative, not yet counted
 
 
 def _write_slot(cache_tree, slot_cache_tree, slot: int):
@@ -77,7 +86,6 @@ class ServingEngine:
         self.slots = [Slot(i) for i in range(max_batch)]
         self.pending_tokens = np.zeros((max_batch,), np.int32)
         self.steps_executed = 0
-        self.spec_steps_executed = 0
 
         # per-leaf batch axis, derived structurally (a size-1 probe cache
         # differs from the batched cache exactly at the batch axis)
@@ -93,7 +101,8 @@ class ServingEngine:
         def _mask_batch(new, old, mask, axis):
             shape = [1] * new.ndim
             shape[axis] = mask.shape[0]
-            return jnp.where(mask.reshape(shape), new, old)
+            with jax.named_scope("engine.active_mask"):
+                return jnp.where(mask.reshape(shape), new, old)
 
         @jax.jit
         def _decode(params, cache, tokens, active_mask):
@@ -159,11 +168,21 @@ class ServingEngine:
         s.tokens = list(prompt)
         s.active = True
         s.done = False
-        toks = jnp.asarray([prompt], jnp.int32)
-        nxt, self.last_logits, cache1 = self._prefill_one(self.params, toks)
-        self.cache = _write_slot(self.cache, cache1, slot)
-        self.pending_tokens[slot] = int(nxt[0])
+        s.spec_rows = 0
+        with telemetry.span("engine.prefill", tokens=len(prompt), speculative=int(speculative),
+                            slot=slot, request_id=request_id):
+            toks = jnp.asarray([prompt], jnp.int32)
+            nxt, self.last_logits, cache1 = self._prefill_one(self.params, toks)
+            with telemetry.span("engine.prefill.write_slot"):
+                self.cache = _write_slot(self.cache, cache1, slot)
+            with telemetry.span("engine.prefill.wait"):
+                self.pending_tokens[slot] = int(nxt[0])
         return slot
+
+    def _release(self, s: Slot, outcome: str) -> None:
+        """Count the rows a speculative slot decoded, where it leaves."""
+        telemetry.count("engine.slot_release", s.spec_rows, outcome=outcome)
+        s.spec_rows = 0
 
     def preempt(self, slot: int):
         """Reclaim a speculative slot at a step boundary (drop, zero-copy)."""
@@ -171,6 +190,7 @@ class ServingEngine:
         assert s.speculative, "authoritative slots are never preempted"
         s.active = False
         s.request_id = None
+        self._release(s, "preempt")
 
     def promote(self, slot: int, request_id: int):
         """Speculative -> authoritative (non-preemptible), zero-copy."""
@@ -178,29 +198,36 @@ class ServingEngine:
         s.speculative = False
         s.request_id = request_id
         s.eu = float("inf")
+        self._release(s, "promote")
 
     def step(self) -> Dict[int, int]:
         """One batched decode step; returns {slot: new_token} for active slots."""
-        active = np.array([s.active and not s.done for s in self.slots])
-        if not active.any():
+        live = [s for s in self.slots if s.active and not s.done]
+        if not live:
             return {}
-        tokens = jnp.asarray(self.pending_tokens, jnp.int32)
-        nxt, self.last_logits, self.cache = self._decode(
-            self.params, self.cache, tokens, jnp.asarray(active))
-        nxt = np.asarray(nxt)
-        out: Dict[int, int] = {}
-        self.steps_executed += 1
-        self.spec_steps_executed += int(
-            sum(1 for s in self.slots if s.active and s.speculative)
-        )
-        for s in self.slots:
-            if not (s.active and not s.done):
-                continue
-            tok = int(nxt[s.idx])
-            s.tokens.append(int(self.pending_tokens[s.idx]))
-            self.pending_tokens[s.idx] = tok
-            out[s.idx] = tok
-            if tok == self.eos_id or len(s.tokens) >= self.max_len - 1:
-                s.done = True
-                s.active = False
+        n_spec = sum(1 for s in live if s.speculative)
+        with telemetry.span("engine.decode", active=len(live), speculative=n_spec):
+            with telemetry.span("engine.decode.prep"):
+                active = np.zeros((self.max_batch,), bool)
+                active[[s.idx for s in live]] = True
+                tokens = jnp.asarray(self.pending_tokens, jnp.int32)
+                nxt, self.last_logits, self.cache = self._decode(
+                    self.params, self.cache, tokens, jnp.asarray(active))
+            with telemetry.span("engine.decode.wait"):
+                nxt = np.asarray(nxt)
+            with telemetry.span("engine.decode.emit"):
+                out: Dict[int, int] = {}
+                self.steps_executed += 1
+                for s in live:
+                    tok = int(nxt[s.idx])
+                    s.tokens.append(int(self.pending_tokens[s.idx]))
+                    self.pending_tokens[s.idx] = tok
+                    out[s.idx] = tok
+                    if s.speculative:
+                        s.spec_rows += 1
+                    if tok == self.eos_id or len(s.tokens) >= self.max_len - 1:
+                        s.done = True
+                        s.active = False
+                        if s.speculative:
+                            self._release(s, "done")
         return out

@@ -12,6 +12,7 @@ process may load the TPU library, and every test worker imports this file.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -52,9 +53,16 @@ def _on(tree, sharding):
     return jax.tree.map(lambda a: _spec(a.shape, a.dtype, sharding), tree)
 
 
-def _compile_with_kernel(fn, *args):
+def _compile_with_kernel(fn, *args, kernel=None):
+    """Compile ``fn``; its program must hold a Pallas kernel, and with
+    ``kernel`` every one must carry that name (``pallas_call(name=...)``) in
+    its op metadata, which the profiler's trace reports."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text(), "no Pallas kernel in the program"
+    calls = [ln for ln in compiled.as_text().splitlines() if "tpu_custom_call" in ln]
+    assert calls, "no Pallas kernel in the program"
+    if kernel is not None:
+        named = re.compile(rf'op_name="[^"]*/{kernel}/pallas_call"')
+        assert all(named.search(ln) for ln in calls), calls
     return compiled
 
 
@@ -65,7 +73,8 @@ def test_flash_attention_compiles_at_granite_widths(one_chip, seq):
     q = _spec((1, seq, cfg.n_heads, hd), jnp.bfloat16, one_chip)
     kv = _spec((1, seq, cfg.n_kv_heads, hd), jnp.bfloat16, one_chip)
     _compile_with_kernel(
-        functools.partial(ops.flash_attention, causal=True, impl="pallas"), q, kv, kv)
+        functools.partial(ops.flash_attention, causal=True, impl="pallas"), q, kv, kv,
+        kernel="flash_attention")
 
 
 def test_decode_attention_compiles_at_granite_widths(one_chip):
@@ -75,7 +84,8 @@ def test_decode_attention_compiles_at_granite_widths(one_chip):
     cache = _spec((8, 2048, cfg.n_kv_heads, hd), jnp.bfloat16, one_chip)
     lengths = _spec((8,), jnp.int32, one_chip)
     _compile_with_kernel(
-        functools.partial(ops.decode_attention, impl="pallas"), q, cache, cache, lengths)
+        functools.partial(ops.decode_attention, impl="pallas"), q, cache, cache, lengths,
+        kernel="decode_attention")
 
 
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
@@ -103,12 +113,13 @@ def test_granite_step_compiles_at_two_layers(one_chip, phase):
             lambda: model_mod.init_cache(cfg, batch, max_len)), one_chip)
         tokens = _spec((batch,), jnp.int32, one_chip)
         compiled = _compile_with_kernel(
-            lambda p, c, t: model_mod.decode_step(p, cfg, c, t), params, cache, tokens)
+            lambda p, c, t: model_mod.decode_step(p, cfg, c, t), params, cache, tokens,
+            kernel="decode_attention")
     else:
         tokens = _spec((1, 1024), jnp.int32, one_chip)
         compiled = _compile_with_kernel(
             lambda p, t: model_mod.prefill(p, cfg, {"tokens": t}, max_len),
-            params, tokens)
+            params, tokens, kernel="flash_attention")
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
