@@ -180,10 +180,17 @@ def decode_attention(
     window: Optional[int] = None,
     impl: str = "auto",
     block_k: int = 512,
+    layer: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
-    """Flash-decode. q (B,H,D), cache (B,Smax,KV,D), lengths (B,) -> (B,H,D)."""
+    """Flash-decode. q (B,H,D), cache (B,Smax,KV,D), lengths (B,) -> (B,H,D).
+
+    The caches may instead be the stacks (L,B,Smax,KV,D) of every layer,
+    with ``layer`` (a scalar) the one the query attends to: the Pallas
+    kernel reads it in place, the jnp paths slice it out."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     impl = _resolve(impl)
+    if k_cache.ndim == 5 and impl not in ("pallas", "pallas_interpret"):
+        k_cache, v_cache = k_cache[layer], v_cache[layer]
     if impl == "ref":
         return _ref.decode_attention_reference(
             q, k_cache, v_cache, lengths, scale=scale, window=window
@@ -196,7 +203,7 @@ def decode_attention(
 
     return _da.decode_attention_pallas(
         q, k_cache, v_cache, lengths, scale=scale, window=window,
-        block_k=block_k, interpret=(impl == "pallas_interpret"),
+        block_k=block_k, interpret=(impl == "pallas_interpret"), layer=layer,
     )
 
 

@@ -102,57 +102,55 @@ def attn_prefill(p, x, cfg: ModelConfig, smax: int, *, impl: str):
     return out, k_cache, v_cache
 
 
+def _rolling(cfg: ModelConfig, smax: int) -> bool:
+    return cfg.sliding_window is not None and smax <= cfg.sliding_window
+
+
 def attn_decode(
-    p, x, k_cache, v_cache, lengths, cfg: ModelConfig, *, impl: str
+    p, x, k_cache, v_cache, lengths, layer, cfg: ModelConfig, *, impl: str
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One-token decode.  x (B,1,D); cache (B,Smax,KV,hd) — or, with an int8
-    cache, a (values int8, scales f32) pair; lengths (B,) = tokens already in
-    cache.  Returns (out (B,1,D), new_k_cache, new_v_cache)."""
+    """One-token decode of layer ``layer`` against the stacked cache.
+
+    x (B,1,D); cache (L,B,Smax,KV,hd) — or, with an int8 cache, a (values
+    int8, scales f32) pair of stacks; lengths (B,) = tokens already in cache;
+    layer a scalar index into the stack.  The new K/V row of each sequence is
+    written in place at ``[layer, b, slot_b]``; nothing else of the stack is
+    rewritten.  Returns (out (B,1,D), new_k_cache, new_v_cache)."""
     b = x.shape[0]
     quant = cfg.kv_cache_dtype == "int8"
-    kq = ks = vq = vs = None
-    if quant:
-        kq, ks = k_cache
-        vq, vs = v_cache
-        smax = kq.shape[1]
-    else:
-        smax = k_cache.shape[1]
+    smax = (k_cache[0] if quant else k_cache).shape[2]
     q, k, v = _qkv(p, x, cfg)                     # (B,1,H,hd)/(B,1,KV,hd)
     q = apply_rope(q, lengths[:, None], cfg.rope_theta)
     k = apply_rope(k, lengths[:, None], cfg.rope_theta)
-    if cfg.sliding_window is not None and smax <= cfg.sliding_window:
-        # rolling buffer: slot = lengths % smax
-        slot = lengths % smax
-    else:
-        slot = jnp.minimum(lengths, smax - 1)
-    bidx = jnp.arange(b)
+    rolling = _rolling(cfg, smax)
+    slot = lengths % smax if rolling else jnp.minimum(lengths, smax - 1)
+    idx = (layer, jnp.arange(b), slot)
     if quant:
+        (kq, ks), (vq, vs) = k_cache, v_cache
         knq, kns = kv_quantize(k[:, 0])
         vnq, vns = kv_quantize(v[:, 0])
-        kq = kq.at[bidx, slot].set(knq)
-        ks = ks.at[bidx, slot].set(kns)
-        vq = vq.at[bidx, slot].set(vnq)
-        vs = vs.at[bidx, slot].set(vns)
-        k_cache = kv_dequantize(kq, ks, x.dtype)
-        v_cache = kv_dequantize(vq, vs, x.dtype)
+        k_cache = (kq.at[idx].set(knq), ks.at[idx].set(kns))
+        v_cache = (vq.at[idx].set(vnq), vs.at[idx].set(vns))
+        # attention reads this layer dequantized
+        k_att = kv_dequantize(k_cache[0][layer], k_cache[1][layer], x.dtype)
+        v_att = kv_dequantize(v_cache[0][layer], v_cache[1][layer], x.dtype)
     else:
-        k_cache = k_cache.at[bidx, slot].set(k[:, 0])
-        v_cache = v_cache.at[bidx, slot].set(v[:, 0])
-    if cfg.sliding_window is not None and smax <= cfg.sliding_window:
+        k_cache = k_cache.at[idx].set(k[:, 0])
+        v_cache = v_cache.at[idx].set(v[:, 0])
+        # attention reads the layer out of the stack where it lies
+        k_att, v_att = k_cache, v_cache
+    if rolling:
         # cache holds a rotated window; decode attention masks by min(len+1, smax)
-        eff_len = jnp.minimum(lengths + 1, smax)
         # NOTE: positions are rotated; softmax is permutation-invariant so a
         # rotated cache is fine as long as RoPE was applied pre-insertion.
-        o = ops.decode_attention(q[:, 0], k_cache, v_cache, eff_len, impl=impl)
+        o = ops.decode_attention(q[:, 0], k_att, v_att, jnp.minimum(lengths + 1, smax),
+                                 impl=impl, layer=layer)
     else:
         o = ops.decode_attention(
-            q[:, 0], k_cache, v_cache, lengths + 1,
-            window=cfg.sliding_window, impl=impl,
+            q[:, 0], k_att, v_att, lengths + 1, window=cfg.sliding_window, impl=impl,
+            layer=layer,
         )
-    out = o.reshape(b, 1, -1) @ p["wo"]
-    if quant:
-        return out, (kq, ks), (vq, vs)
-    return out, k_cache, v_cache
+    return o.reshape(b, 1, -1) @ p["wo"], k_cache, v_cache
 
 
 # ======================================================================
@@ -168,10 +166,11 @@ def attn_decode(
 # ======================================================================
 
 def attn_decode_sharded(
-    p, x, k_cache, v_cache, lengths, cfg: ModelConfig, mesh_info,
+    p, x, k_cache, v_cache, lengths, layer, cfg: ModelConfig, mesh_info,
 ):
-    """Decode with a sequence-sharded KV cache.  x (B,1,D); caches
-    (B,Smax,KV,hd) sharded on axis 1 over `model`.  Returns
+    """Decode of layer ``layer`` with a sequence-sharded stacked KV cache.
+    x (B,1,D); caches (L,B,Smax,KV,hd) sharded on axis 2 over `model`; each
+    shard writes the new row in place if it holds the row's slot.  Returns
     (out (B,1,D), new_k_cache, new_v_cache)."""
     import jax
     from jax.sharding import PartitionSpec as P
@@ -180,9 +179,9 @@ def attn_decode_sharded(
     quant = cfg.kv_cache_dtype == "int8"
     if quant:
         (kq_c, ks_c), (vq_c, vs_c) = k_cache, v_cache
-        smax = kq_c.shape[1]
+        smax = kq_c.shape[2]
     else:
-        smax = k_cache.shape[1]
+        smax = k_cache.shape[2]
     KV, hd = cfg.eff_n_kv_heads, cfg.resolved_head_dim
     H = cfg.eff_n_heads
     grp = H // KV
@@ -196,7 +195,7 @@ def attn_decode_sharded(
     qg = (q[:, 0].astype(jnp.float32) * scale).reshape(b, KV, grp, hd)
     k_new, v_new = k[:, 0], v[:, 0]                # (B,KV,hd)
 
-    rolling = cfg.sliding_window is not None and smax <= cfg.sliding_window
+    rolling = _rolling(cfg, smax)
     slot = (lengths % smax) if rolling else jnp.minimum(lengths, smax - 1)
     eff_len = jnp.minimum(lengths + 1, smax) if rolling else lengths + 1
 
@@ -205,27 +204,26 @@ def attn_decode_sharded(
     ma = mesh_info.model_axis
     NEG = -1e30
 
-    def body(qg_l, kn_l, vn_l, kc_l, vc_l, slot_l, eff_l, *scales):
+    def body(qg_l, kn_l, vn_l, kc_l, vc_l, slot_l, eff_l, layer_l, *scales):
         rank = jax.lax.axis_index(ma)
         off = rank * chunk
-        bidx = jnp.arange(qg_l.shape[0])
         loc = jnp.clip(slot_l - off, 0, chunk - 1)
         in_rng = (slot_l >= off) & (slot_l < off + chunk)
-        cur_k = kc_l[bidx, loc]
-        cur_v = vc_l[bidx, loc]
-        kc_l = kc_l.at[bidx, loc].set(jnp.where(in_rng[:, None, None], kn_l, cur_k))
-        vc_l = vc_l.at[bidx, loc].set(jnp.where(in_rng[:, None, None], vn_l, cur_v))
+        idx = (layer_l, jnp.arange(qg_l.shape[0]), loc)
+
+        def put(c, new):
+            keep = in_rng.reshape(in_rng.shape + (1,) * (new.ndim - 1))
+            return c.at[idx].set(jnp.where(keep, new, c[idx]))
+
+        kc_l, vc_l = put(kc_l, kn_l), put(vc_l, vn_l)
         if quant:
             ks_l, vs_l, kns_l, vns_l = scales
-            cur_ks = ks_l[bidx, loc]
-            cur_vs = vs_l[bidx, loc]
-            ks_l = ks_l.at[bidx, loc].set(jnp.where(in_rng[:, None], kns_l, cur_ks))
-            vs_l = vs_l.at[bidx, loc].set(jnp.where(in_rng[:, None], vns_l, cur_vs))
-            k_eff = (kc_l.astype(jnp.float32) * ks_l[..., None]).astype(x.dtype)
-            v_eff = (vc_l.astype(jnp.float32) * vs_l[..., None]).astype(x.dtype)
+            ks_l, vs_l = put(ks_l, kns_l), put(vs_l, vns_l)
+            k_eff = (kc_l[layer_l].astype(jnp.float32) * ks_l[layer_l][..., None]).astype(x.dtype)
+            v_eff = (vc_l[layer_l].astype(jnp.float32) * vs_l[layer_l][..., None]).astype(x.dtype)
         else:
-            k_eff = kc_l
-            v_eff = vc_l
+            k_eff = kc_l[layer_l]
+            v_eff = vc_l[layer_l]
         # local partial flash-decode over this shard's cache slice — on TPU
         # this is the Pallas partials kernel (kernels/decode_attention.py),
         # on CPU the identical jnp path; only (m, l, acc) cross the ICI.
@@ -249,38 +247,39 @@ def attn_decode_sharded(
         P(b_ax, None, None, None),      # qg
         P(b_ax, None, None),            # k_new
         P(b_ax, None, None),            # v_new
-        P(b_ax, ma, None, None),        # k_cache (seq-sharded)
-        P(b_ax, ma, None, None),        # v_cache
+        P(None, b_ax, ma, None, None),  # k_cache (seq-sharded)
+        P(None, b_ax, ma, None, None),  # v_cache
         P(b_ax),                        # slot
         P(b_ax),                        # eff_len
+        P(),                            # layer
     ]
-    out_specs = [P(b_ax, None, None, None), P(b_ax, ma, None, None),
-                 P(b_ax, ma, None, None)]
+    out_specs = [P(b_ax, None, None, None), P(None, b_ax, ma, None, None),
+                 P(None, b_ax, ma, None, None)]
     if quant:
         knq, kns = kv_quantize(k_new)
         vnq, vns = kv_quantize(v_new)
-        in_specs += [P(b_ax, ma, None), P(b_ax, ma, None),   # ks, vs caches
-                     P(b_ax, None), P(b_ax, None)]           # new scales
-        out_specs += [P(b_ax, ma, None), P(b_ax, ma, None)]
+        in_specs += [P(None, b_ax, ma, None), P(None, b_ax, ma, None),  # ks, vs caches
+                     P(b_ax, None), P(b_ax, None)]                      # new scales
+        out_specs += [P(None, b_ax, ma, None), P(None, b_ax, ma, None)]
         fn = compat.shard_map(body, mesh=mesh_info.mesh, in_specs=tuple(in_specs),
                               out_specs=tuple(out_specs))
-        o, kq_c, vq_c, ks_c, vs_c = fn(qg, knq, vnq, kq_c, vq_c, slot, eff_len,
+        o, kq_c, vq_c, ks_c, vs_c = fn(qg, knq, vnq, kq_c, vq_c, slot, eff_len, layer,
                                        ks_c, vs_c, kns, vns)
         out = o.reshape(b, 1, H * hd) @ p["wo"]
         return out, (kq_c, ks_c), (vq_c, vs_c)
     fn = compat.shard_map(body, mesh=mesh_info.mesh, in_specs=tuple(in_specs),
                           out_specs=tuple(out_specs))
-    o, k_cache, v_cache = fn(qg, k_new, v_new, k_cache, v_cache, slot, eff_len)
+    o, k_cache, v_cache = fn(qg, k_new, v_new, k_cache, v_cache, slot, eff_len, layer)
     out = o.reshape(b, 1, H * hd) @ p["wo"]
     return out, k_cache, v_cache
 
 
-def attn_decode_dispatch(p, x, k_cache, v_cache, lengths, cfg: ModelConfig,
+def attn_decode_dispatch(p, x, k_cache, v_cache, lengths, layer, cfg: ModelConfig,
                          mesh_info, *, impl: str):
     """Choose the sharded split-KV path when enabled and applicable."""
-    smax_chk = (k_cache[0] if cfg.kv_cache_dtype == "int8" else k_cache).shape[1]
+    smax_chk = (k_cache[0] if cfg.kv_cache_dtype == "int8" else k_cache).shape[2]
     if (cfg.sharded_decode_attn and mesh_info is not None
             and mesh_info.n_model > 1
             and smax_chk % mesh_info.n_model == 0):
-        return attn_decode_sharded(p, x, k_cache, v_cache, lengths, cfg, mesh_info)
-    return attn_decode(p, x, k_cache, v_cache, lengths, cfg, impl=impl)
+        return attn_decode_sharded(p, x, k_cache, v_cache, lengths, layer, cfg, mesh_info)
+    return attn_decode(p, x, k_cache, v_cache, lengths, layer, cfg, impl=impl)

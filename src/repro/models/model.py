@@ -11,7 +11,8 @@ Design notes
 * **frontend stubs** (vlm/audio): prefill/train consume precomputed
   patch/frame embeddings (B,S,D) through a learned adapter; decode consumes
   token ids through the LM embedding table (text / EnCodec codes).
-* caches are dicts of stacked per-layer arrays so decode is also a scan.
+* caches are dicts of stacked per-layer arrays so decode is also a scan; the
+  K/V stacks ride in its carry and each layer writes its new rows in place.
 """
 from __future__ import annotations
 
@@ -344,12 +345,13 @@ def decode_step(
     new_cache: Dict[str, Any] = {"lengths": lengths + 1}
 
     if cfg.family in ("dense", "vlm", "audio", "moe"):
-        def body(h, xs):
-            blk, kc, vc = xs
+        def body(carry, xs):
+            h, kc, vc = carry
+            blk, layer = xs
             with jax.named_scope("attention"):
                 x = rms_norm(h, blk["ln1"], cfg.norm_eps)
                 o, kc, vc = attn_mod.attn_decode_dispatch(
-                    blk["attn"], x, kc, vc, lengths, cfg, mesh_info, impl=impl)
+                    blk["attn"], x, kc, vc, lengths, layer, cfg, mesh_info, impl=impl)
                 h = h + o
             with jax.named_scope("mlp"):
                 if cfg.family == "moe":
@@ -357,13 +359,14 @@ def decode_step(
                     h = h + y
                 else:
                     h = h + mlp_mod.mlp_forward(blk["mlp"], rms_norm(h, blk["ln2"], cfg.norm_eps))
-            return h, (kc, vc)
+            return (h, kc, vc), None
 
-        # the loop slices each layer's cache out of the stacked cache and
-        # writes it back whole (its ``dynamic_update_slice``): a scope on the
-        # loop names that write-back, the body's scopes name the rest
+        # the stacked cache rides in the loop carry: each layer writes its
+        # new rows in place, and no layer's cache is copied in or out
         with jax.named_scope("layers"):
-            h, (kc, vc) = jax.lax.scan(body, h, (params["blocks"], cache["k"], cache["v"]))
+            (h, kc, vc), _ = jax.lax.scan(
+                body, (h, cache["k"], cache["v"]),
+                (params["blocks"], jnp.arange(cfg.n_layers)))
         new_cache["k"], new_cache["v"] = kc, vc
         return lm_logits(params, cfg, h[:, 0]), new_cache
 
@@ -393,16 +396,17 @@ def decode_step(
         a[: g * kpg].reshape((g, kpg) + a.shape[1:]) for a in cache["ssm_state"]
     )
 
-    def outer(h, xs):
-        grp_blocks, st_g, kc, vc = xs
+    def outer(carry, xs):
+        h, kc, vc = carry
+        grp_blocks, st_g, gi = xs
         h, st_g = jax.lax.scan(inner, h, (grp_blocks, st_g))
         x = rms_norm(h, shared["ln"], cfg.norm_eps)
         o, kc, vc = attn_mod.attn_decode_dispatch(
-            shared["attn"], x, kc, vc, lengths, cfg, mesh_info, impl=impl)
-        return h + o, (st_g, kc, vc)
+            shared["attn"], x, kc, vc, lengths, gi, cfg, mesh_info, impl=impl)
+        return (h + o, kc, vc), st_g
 
-    h, (st_g, kc, vc) = jax.lax.scan(
-        outer, h, (main_blocks, main_st, cache["k"], cache["v"])
+    (h, kc, vc), st_g = jax.lax.scan(
+        outer, (h, cache["k"], cache["v"]), (main_blocks, main_st, jnp.arange(g))
     )
     new_st = tuple(a.reshape((g * kpg,) + a.shape[2:]) for a in st_g)
     if tail:

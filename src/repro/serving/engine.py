@@ -65,7 +65,37 @@ def _write_slot(cache_tree, slot_cache_tree, slot: int):
     return jax.tree.map(upd, cache_tree, slot_cache_tree)
 
 
+def make_decode(cfg: ModelConfig, mesh_info=None):
+    """The engine's decode program: one batched step over every slot.
+
+    ``(params, cache, tokens, active_mask) -> (next_tokens, logits, cache)``.
+    The cache is donated and updated in place: each step writes one K/V row
+    per slot and nothing else of the cache.  Inactive slots decode too; only
+    their ``lengths`` are held back by the mask (see ``ServingEngine``)."""
+
+    def _decode(params, cache, tokens, active_mask):
+        logits, new_cache = model_mod.decode_step(params, cfg, cache, tokens, mesh_info)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        # inactive slots do not advance
+        with jax.named_scope("engine.active_mask"):
+            lengths = jnp.where(active_mask, new_cache["lengths"], cache["lengths"])
+        return nxt, logits, {**new_cache, "lengths": lengths}
+
+    return jax.jit(_decode, donate_argnums=1)
+
+
 class ServingEngine:
+    """Slots over one batched cache, decoded together one step at a time.
+
+    Dead-slot invariant: the K/V rows and states of an inactive slot are
+    never read before they are rewritten.  A slot turns active only in
+    ``add_request``, whose ``_write_slot`` rewrites the whole slot, lengths
+    included, from the prefill's cache; ``preempt``, a slot finishing, and a
+    caller clearing ``Slot.active`` only deactivate.  So the decode step lets
+    inactive slots write their one row, at position ``lengths`` (outside the
+    slot's valid range, or in a dead window of a rolling buffer), and masks
+    only ``lengths``: served tokens of active slots do not depend on it."""
+
     def __init__(
         self,
         cfg: ModelConfig,
@@ -87,35 +117,7 @@ class ServingEngine:
         self.pending_tokens = np.zeros((max_batch,), np.int32)
         self.steps_executed = 0
 
-        # per-leaf batch axis, derived structurally (a size-1 probe cache
-        # differs from the batched cache exactly at the batch axis)
-        big_s = jax.eval_shape(lambda: model_mod.init_cache(cfg, max_batch, max_len))
-        small_s = jax.eval_shape(lambda: model_mod.init_cache(cfg, 1, max_len))
-        self._batch_axes = jax.tree.map(
-            lambda b, sm: next(
-                (i for i, (x, y) in enumerate(zip(b.shape, sm.shape, strict=False)) if x != y), 0
-            ),
-            big_s, small_s,
-        )
-
-        def _mask_batch(new, old, mask, axis):
-            shape = [1] * new.ndim
-            shape[axis] = mask.shape[0]
-            with jax.named_scope("engine.active_mask"):
-                return jnp.where(mask.reshape(shape), new, old)
-
-        @jax.jit
-        def _decode(params, cache, tokens, active_mask):
-            logits, new_cache = model_mod.decode_step(params, cfg, cache, tokens, mesh_info)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            # inactive slots do not advance
-            new_cache = jax.tree.map(
-                lambda new, old, ax: _mask_batch(new, old, active_mask, ax),
-                new_cache, cache, self._batch_axes,
-            )
-            return nxt, logits, new_cache
-
-        self._decode = _decode
+        self._decode = make_decode(cfg, mesh_info)
 
         @jax.jit
         def _prefill_one(params, tokens):

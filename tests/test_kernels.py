@@ -62,6 +62,21 @@ def test_decode_attention(impl, shape, dtype, window):
                                np.asarray(want, np.float32), atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("impl", ["jnp", "pallas_interpret"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_decode_attention_reads_a_layer_of_the_stack(impl, layer):
+    """With ``layer``, the caches are every layer's stack and the query
+    attends to that layer's cache alone."""
+    b, h, kv, d, smax = 2, 8, 2, 64, 200
+    q = _mk((b, h, d), jnp.float32)
+    kc, vc = _mk((3, b, smax, kv, d), jnp.float32), _mk((3, b, smax, kv, d), jnp.float32)
+    lens = jnp.asarray([37, 200], jnp.int32)
+    want = ref.decode_attention_reference(q, kc[layer], vc[layer], lens)
+    got = ops.decode_attention(q, kc, vc, lens, impl=impl, block_k=64,
+                               layer=jnp.int32(layer))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
 SSD_SHAPES = [
     # (B, S, H, P, G, N, chunk)
     (1, 96, 2, 16, 1, 16, 32),

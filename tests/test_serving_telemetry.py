@@ -1,6 +1,7 @@
 """The serving engine's own spans and counters (``serving/telemetry.py``), as a
 ``jax.monitoring`` listener receives them, and the device-side names of the
 decode program."""
+import dataclasses
 import time
 
 import jax
@@ -10,7 +11,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import model as M
-from repro.serving.engine import ServingEngine
+from repro.serving.engine import ServingEngine, make_decode
 
 MAX_LEN = 32
 PARENT = {"engine.prefill.write_slot": "engine.prefill", "engine.prefill.wait": "engine.prefill",
@@ -119,11 +120,23 @@ def test_engine_runs_with_no_listener(engine, monkeypatch):
 
 
 def test_decode_program_names_its_mask_and_cache_write_back(engine):
+    """The decode program names its mask and its parts, and writes the cache
+    back in place: every cache argument is donated to an output, and, lowered
+    for the TPU (where the decode kernel reads the stacked cache itself), no
+    op outputs one layer's K/V and no select runs over the cache."""
     b = engine.max_batch
-    text = engine._decode.lower(
-        engine.params, engine.cache, jax.ShapeDtypeStruct((b,), jnp.int32),
-        jax.ShapeDtypeStruct((b,), jnp.bool_)).as_text(debug_info=True)
+    args = (engine.params, engine.cache, jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.bool_))
+    text = engine._decode.lower(*args).as_text(debug_info=True)
     assert '"jit(_decode)/engine.active_mask/' in text
-    # the layer loop's write-back of each layer's cache, outside the body's scopes
-    assert '"jit(_decode)/layers/while/body/dynamic_update_slice"' in text
     assert '"attention/' in text and '"mlp/' in text
+    main = next(ln for ln in text.splitlines() if "func.func public @main" in ln)
+    assert main.count("tf.aliasing_output") == len(jax.tree.leaves(engine.cache))
+
+    tpu = make_decode(dataclasses.replace(engine.cfg, attn_impl="pallas")).trace(
+        *args).lower(lowering_platforms=("tpu",)).as_text()
+    k = engine.cache["k"]
+    stack = "tensor<" + "x".join(map(str, k.shape)) + "x"
+    assert stack in tpu
+    assert "tensor<" + "x".join(map(str, k.shape[1:])) + "x" not in tpu
+    assert not [ln for ln in tpu.splitlines() if "stablehlo.select" in ln and stack in ln]

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels import ops
 from repro.models import model as model_mod
+from repro.serving.engine import make_decode
 
 V5E_HBM_BYTES = 16 * 1024**3
 
@@ -88,6 +89,21 @@ def test_decode_attention_compiles_at_granite_widths(one_chip):
         kernel="decode_attention")
 
 
+@pytest.mark.parametrize("arch", ["granite-8b", "deepseek-7b"])
+def test_decode_attention_compiles_on_a_stacked_cache(one_chip, arch):
+    """The decode kernel reads one layer of a (L, 16, 2048, KV, hd) stack in
+    place; its tiles fit the kernel's VMEM for 8 KV heads and for 32."""
+    cfg = get_config(arch)
+    hd = cfg.resolved_head_dim
+    q = _spec((16, cfg.n_heads, hd), jnp.bfloat16, one_chip)
+    cache = _spec((4, 16, 2048, cfg.n_kv_heads, hd), jnp.bfloat16, one_chip)
+    lengths = _spec((16,), jnp.int32, one_chip)
+    layer = _spec((), jnp.int32, one_chip)
+    _compile_with_kernel(
+        lambda q, k, v, n, i: ops.decode_attention(q, k, v, n, impl="pallas", layer=i),
+        q, cache, cache, lengths, layer, kernel="decode_attention")
+
+
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
     cfg = get_config("mamba2-2.7b")
     s = cfg.ssm
@@ -124,3 +140,23 @@ def test_granite_step_compiles_at_two_layers(one_chip, phase):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < V5E_HBM_BYTES
+
+
+def test_engine_decode_writes_the_cache_in_place(one_chip):
+    """The engine's decode step at granite widths, depth cut to 4, 16 slots
+    of 2048: the donated cache is aliased to the output, and no temporary as
+    large as the cache is left (a copy of one layer's slice would fit under
+    that bound; a whole-cache select or write-back would not)."""
+    cfg = dataclasses.replace(get_config("granite-8b"), n_layers=4, attn_impl="pallas")
+    batch, max_len = 16, 2048
+    params = _on(jax.eval_shape(
+        functools.partial(model_mod.init_params, cfg=cfg), jax.random.key(0)), one_chip)
+    cache = _on(jax.eval_shape(lambda: model_mod.init_cache(cfg, batch, max_len)), one_chip)
+    compiled = make_decode(cfg).lower(
+        params, cache, _spec((batch,), jnp.int32, one_chip),
+        _spec((batch,), jnp.bool_, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes
