@@ -2,9 +2,21 @@
 
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by its name under the benchmark's directory:
-``configs/<config>.json``, ``traffic/<traffic>.json`` (naming its generator,
-``generators/<generator>.py``) and ``metrics/<metric>.py`` (a ``read(rec)``
-that returns a number, or None where it finds nothing to read).
+``configs/<config>.json`` (naming its architecture,
+``archs/<architecture>.py``, and its reference,
+``references/<reference>.py``), ``traffic/<traffic>.json`` (naming its
+generator, ``generators/<generator>.py``) and ``metrics/<metric>.py`` (a
+``read(rec)`` that returns a number, or None where it finds nothing to read).
+
+An architecture gives ``model_config(cfg)``, the program's ``ModelConfig``,
+and ``served_params(cfg, mcfg, seed, ref)``, the whole parameter tree made on
+the device.  A reference imports nothing of the program and gives
+``dims(cfg)`` (the record's ``dims``), ``layer_weights`` (the seeded draw both
+sides use), ``layer(h, w, cfg, lower)`` (one layer of the float32 pass that
+``chipbench/reference.Layerwise`` runs), and ``decode_work(dims, rows,
+sum_ctx)`` and ``prefill_work(dims, n)``: the model's operations and, per
+Pallas kernel by name, its operations and bytes; the record carries the
+module as ``ref``.
 """
 from __future__ import annotations
 
@@ -18,7 +30,7 @@ import tempfile
 import time
 from typing import Dict, List, Optional
 
-from chipbench import results, spans as spans_mod, weights as W
+from chipbench import results, spans as spans_mod
 
 TRACE_S = 5.0           # length of the traced stretch of a --trace 1 window
 TRACE_AT = 0.4          # where it starts, as a share of the window
@@ -95,6 +107,11 @@ class Cell:
         self.cell = find_cell(self.bench, workload)
         self.name = workload
         self.cfg = load_json(os.path.join(bench_root, "configs", self.cell["config"] + ".json"))
+        aname, rname = self.cfg["architecture"], self.cfg["reference"]
+        self.arch = load_module(os.path.join(bench_root, "archs", aname + ".py"),
+                                "arch_" + aname.replace(".", "_"))
+        self.ref = load_module(os.path.join(bench_root, "references", rname + ".py"),
+                               "ref_" + rname.replace(".", "_"))
         self.traffic = load_json(os.path.join(bench_root, "traffic",
                                               self.cell["traffic"] + ".json"))
         gname = self.traffic["generator"]
@@ -124,7 +141,7 @@ def measure(c: Cell, seed: int, seconds: float, trace: bool, *, require_tpu: boo
     gen, traffic = c.gen, c.traffic
     roster = gen.load_roster(traffic)
     t_phase = time.perf_counter()
-    engine, spec, builder = system.build(c.cfg, seed, roster)
+    engine, spec, builder = system.build(c.cfg, seed, roster, c.arch, c.ref)
     jax.block_until_ready(engine.params)
     print(f"set-up: JAX and the device {t_phase - t_start:.3f} s, weights "
           f"{time.perf_counter() - t_phase:.3f} s", file=err)
@@ -169,7 +186,8 @@ def measure(c: Cell, seed: int, seconds: float, trace: bool, *, require_tpu: boo
     if tr["state"] == "on":
         on_tick(float("inf"))
     stats = dev.memory_stats() or {}
-    rec.update(seed=seed, setup_s=setup_s, dims=W.dims(c.cfg), peaks=c.peaks[dev.device_kind],
+    rec.update(seed=seed, setup_s=setup_s, dims=c.ref.dims(c.cfg), ref=c.ref,
+               peaks=c.peaks[dev.device_kind],
                trace_window=(tr["t0"], tr["t1"]), compilations=counter.count,
                rows=engine.cfg.padded_vocab, max_len=engine.max_len,
                device={"platform": dev.platform, "kind": dev.device_kind,
@@ -192,19 +210,19 @@ def compare(c: Cell, rec: Dict, control: bool = False, err=sys.stderr):
     widest gap, and the sample.  With ``control`` also the control's gap:
     the gap of the tokens that the reference, computed in the precision
     below the configuration's, puts first at the same positions."""
-    from chipbench.reference import Reference
+    from chipbench.reference import Layerwise
     picked = results.sample(rec, rec["seed"])
     if not picked:
         return float("inf"), picked, None
     seqs, pos = results.sequences(picked)
     t_ref = time.perf_counter()
-    ref = Reference(c.cfg, rec["seed"], rec["rows"], rec["max_len"]).logits(seqs, pos)
+    ref = Layerwise(c.ref, c.cfg, rec["seed"], rec["rows"], rec["max_len"]).logits(seqs, pos)
     gap = results.widest_gap(ref, [t.tokens for t in picked])
     print(f"reference: {len(picked)} turns, {sum(len(t.tokens) for t in picked)} "
           f"served tokens, {time.perf_counter() - t_ref:.3f} s", file=err)
     ctrl_gap = None
     if control:
-        ctrl = Reference(c.cfg, rec["seed"], rec["rows"], rec["max_len"],
+        ctrl = Layerwise(c.ref, c.cfg, rec["seed"], rec["rows"], rec["max_len"],
                          control=True).logits(seqs, pos)
         ctrl_gap = results.widest_gap(ref, [x.argmax(axis=-1) for x in ctrl])
     return gap, picked, ctrl_gap

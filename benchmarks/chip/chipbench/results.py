@@ -6,7 +6,7 @@ the close serves the ones still open):
 
 - ``turn_p90_s``: due to last reasoning token;
 - ``ttft_p90_s``: due to first token (slot queueing plus prefill);
-- ``itl_p99_ms``: gap between consecutive tokens of one turn;
+- ``itl_p95_ms``: gap between consecutive tokens of one turn;
 - ``tokens_per_s``: authoritative tokens produced inside the window over
   its length.
 
@@ -49,7 +49,7 @@ def end_to_end(rec: Dict) -> Dict[str, float]:
     return {
         "turn_p90_s": _pct([t.end - t.due for t in turns], 90),
         "ttft_p90_s": _pct([t.first - t.due for t in turns], 90),
-        "itl_p99_ms": _pct(gaps, 99) if gaps else float("nan"),
+        "itl_p95_ms": _pct(gaps, 95) if gaps else float("nan"),
         "tokens_per_s": toks / rec["seconds"],
     }
 

@@ -1,5 +1,7 @@
-"""A copy of the benchmark with a tiny configuration, mix and metric added as
-files only, for the CPU tests."""
+"""A copy of the benchmark with tiny configurations, a mix and a metric added
+as files only, for the CPU tests: ``tiny`` of the llama architecture, and
+``tiny_moe`` of a second one, ``toy_moe`` (the program's ``moe`` family),
+whose architecture and reference are new files too (``tests/toy_moe/``)."""
 from __future__ import annotations
 
 import json
@@ -18,6 +20,13 @@ TINY_CONFIG = {
     "engine": {"max_batch": 4, "max_len": 256, "spec_slots": 2},
     "check": {"logit_gap_limit": 0.02},
 }
+
+# mixtral's keys: 4 experts, top-2, ``intermediate_size`` the expert width
+TINY_MOE_CONFIG = dict(TINY_CONFIG, name="tiny_moe", architecture="toy_moe",
+                       reference="toy_moe", num_local_experts=4, num_experts_per_tok=2,
+                       intermediate_size=64, torch_dtype="float32")
+# the second architecture's files, laid out as in the benchmark
+TOY_MOE = os.path.join(BENCH, "tests", "toy_moe")
 
 TINY_MIX = {
     "generator": "react_agents", "agents": 6, "rounds": 16, "roster": "roster.json",
@@ -39,11 +48,16 @@ def read(rec):
 
 def tiny_bench(root: str, **config_overrides) -> str:
     """Copy the benchmark under ``root``, add the tiny files, and return the
-    path of the copy's BENCHMARK.json."""
+    path of the copy's BENCHMARK.json.  ``config_overrides`` apply to
+    ``tiny``."""
     dst = os.path.join(root, "bench")
     shutil.copytree(BENCH, dst, ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    shutil.copytree(TOY_MOE, dst, dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(dst, "configs", "tiny.json"), "w") as f:
         json.dump(dict(TINY_CONFIG, **config_overrides), f)
+    with open(os.path.join(dst, "configs", "tiny_moe.json"), "w") as f:
+        json.dump(TINY_MOE_CONFIG, f)
     with open(os.path.join(dst, "traffic", "tiny_mix.json"), "w") as f:
         json.dump(TINY_MIX, f)
     with open(os.path.join(dst, "metrics", "turns_finished.py"), "w") as f:
@@ -54,14 +68,15 @@ def tiny_bench(root: str, **config_overrides) -> str:
                     "source": "a stand-in for CPU tests; no CPU number is a device metric"}
     with open(os.path.join(dst, "peaks.json"), "w") as f:
         json.dump(peaks, f)
-    cell = "tiny.tiny_mix"
+    cells = ["tiny.tiny_mix", "tiny_moe.tiny_mix"]
     bench = {
-        "workloads": [{"name": cell, "config": "tiny", "traffic": "tiny_mix", "chips": 1}],
+        "workloads": [{"name": c, "config": c.split(".")[0], "traffic": "tiny_mix",
+                       "chips": 1} for c in cells],
         "end_to_end": [{"name": n, "unit": u} for n, u in (
-            ("turn_p90_s", "s"), ("ttft_p90_s", "s"), ("itl_p99_ms", "ms"),
+            ("turn_p90_s", "s"), ("ttft_p90_s", "s"), ("itl_p95_ms", "ms"),
             ("tokens_per_s", "tokens/s"), ("setup_s", "s"))],
-        "per_layer": [{"name": "decode_step_ms", "unit": "ms", "workloads": [cell]},
-                      {"name": "turns_finished", "unit": "turns", "workloads": [cell]}],
+        "per_layer": [{"name": "decode_step_ms", "unit": "ms", "workloads": cells},
+                      {"name": "turns_finished", "unit": "turns", "workloads": cells}],
     }
     path = os.path.join(dst, "BENCHMARK.json")
     with open(path, "w") as f:

@@ -1,8 +1,8 @@
 """Model FLOP utilization of decode (%): the model operations of the active
-rows at their context lengths (``flops.model_decode``), over the decode
-steps that started in the window, over their span time times the chip's
-bf16 peak."""
-from chipbench import flops, readers
+rows at their context lengths (the cell's reference's ``decode_work``), over
+the decode steps that started in the window, over their span time times the
+chip's bf16 peak."""
+from chipbench import readers
 
 
 def read(rec):
@@ -10,5 +10,5 @@ def read(rec):
     t = sum(s[1] - s[0] for s in steps)
     if not t:
         return None
-    work = sum(flops.model_decode(rec["dims"], s[2], s[4]) for s in steps)
+    work = sum(rec["ref"].decode_work(rec["dims"], s[2], s[4])["model_flops"] for s in steps)
     return 100.0 * work / (t * rec["peaks"]["bf16_flops_per_s"])

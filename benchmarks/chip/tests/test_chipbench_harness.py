@@ -34,7 +34,7 @@ def test_run_prints_the_result_line(bench):
     last, err = _run(bench, trace=False)
     assert list(last) == ["correct", "attempted", "failed", "metrics", "device", "compared"]
     assert last["correct"] is True and last["attempted"] > 0 and last["failed"] == 0
-    assert set(last["metrics"]) == {"turn_p90_s", "ttft_p90_s", "itl_p99_ms",
+    assert set(last["metrics"]) == {"turn_p90_s", "ttft_p90_s", "itl_p95_ms",
                                     "tokens_per_s", "setup_s"}
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(last["device"])
     assert last["device"]["platform"] == "cpu"

@@ -10,10 +10,11 @@ import pytest
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH)
 
-from chipbench import flops, results  # noqa: E402
+from chipbench import flops, harness, results, testing  # noqa: E402
 
 DIMS = {"L": 2, "D": 8, "H": 4, "KV": 2, "hd": 2, "F": 16, "V": 10}
 PEAKS = {"bf16_flops_per_s": 1e6, "hbm_bytes_per_s": 1e5}
+LLAMA = harness.load_module(os.path.join(BENCH, "references", "llama.py"), "ref_llama_t")
 
 
 def _metric(name):
@@ -33,7 +34,8 @@ def _turn(due, first, times, tokens=None, failed=False):
 @pytest.fixture
 def rec():
     return {
-        "t0": 0.0, "t_end": 10.0, "seconds": 10.0, "dims": DIMS, "peaks": PEAKS,
+        "t0": 0.0, "t_end": 10.0, "seconds": 10.0, "dims": DIMS, "ref": LLAMA,
+        "peaks": PEAKS,
         # t0 t1 active spec sum_ctx; the last starts after the window
         "steps": [(1.0, 1.1, 4, 1, 100), (2.0, 2.3, 2, 0, 50), (10.5, 10.6, 1, 0, 9)],
         # t0 t1 tokens speculative
@@ -89,6 +91,20 @@ def test_rooflines_read_the_traced_stretch_only(rec):
     assert _metric("flash_prefill_roofline")(rec) == pytest.approx(100 * least / 0.5)
 
 
+def test_counts_come_from_the_records_reference(rec, tmp_path):
+    """A second architecture's reference gives its own counts; one that
+    counts no such kernel leaves the roofline silent."""
+    copy = os.path.dirname(testing.tiny_bench(str(tmp_path)))
+    toy = harness.load_module(os.path.join(copy, "references", "toy_moe.py"), "ref_toy_moe_t")
+    rec["ref"], rec["dims"] = toy, dict(DIMS, E=4, k=2)
+    want = sum(toy.decode_work(rec["dims"], a, c)["model_flops"] for a, c in ((4, 100), (2, 50)))
+    assert want != flops.model_decode(DIMS, 4, 100) + flops.model_decode(DIMS, 2, 50)
+    assert _metric("mfu.decode")(rec) == pytest.approx(100 * want / (0.4 * 1e6))
+    rec["ref"] = SimpleNamespace(decode_work=lambda d, r, c: {"model_flops": 1.0,
+                                                               "kernels": {}})
+    assert _metric("decode_attn_roofline")(rec) is None
+
+
 def test_idle_share_and_no_trace(rec):
     assert _metric("device_idle_share")(rec) == pytest.approx(25.0)
     rec["trace"] = None
@@ -104,7 +120,7 @@ def test_end_to_end(rec):
     assert e["turn_p90_s"] == pytest.approx(1.5 + 0.9 * (2.3 - 1.5))
     assert e["ttft_p90_s"] == pytest.approx(0.5 + 0.9 * 0.2)
     # gaps 400, 1200, 1000 ms
-    assert e["itl_p99_ms"] == pytest.approx(1000 + 0.98 * 200)
+    assert e["itl_p95_ms"] == pytest.approx(1000 + 0.9 * 200)
 
 
 def test_widest_gap():

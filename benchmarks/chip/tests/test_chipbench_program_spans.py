@@ -18,7 +18,7 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, BENCH)
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(BENCH)), "src"))
 
-from chipbench import harness, program_spans, testing, xplane  # noqa: E402
+from chipbench import harness, program_spans, readers, testing, xplane  # noqa: E402
 
 TRACE = os.path.join(BENCH, "data", "trace_program_spans.xplane.pb")
 NEW = ("engine_step_ms", "engine_host_ms_per_step", "engine_prefill_ms_per_ktok",
@@ -168,3 +168,14 @@ def test_idle_time_is_named_by_program_spans():
     program = sum(t for n, t in red["gaps"].items()
                   if n.startswith(("engine.decode", "engine.prefill")))
     assert program > 0.9 * idle
+
+
+@pytest.mark.parametrize("program,kernel", [("jit__decode", "decode_attention"),
+                                            ("jit__prefill_one", "flash_attention")])
+def test_kernel_by_name_is_the_programs_one_kernel(program, kernel):
+    """Each program holds one Pallas kernel, so selecting it by name reads
+    every Pallas operation of its program."""
+    rec = {"trace": xplane.reduce(TRACE)}
+    whole = sum(t for m, _, t, _, k in rec["trace"]["ops"] if m.startswith(program) and k)
+    assert whole > 0
+    assert readers.kernel_time(rec, program, kernel) == whole

@@ -35,8 +35,9 @@ def test_each_kernel_is_found_in_its_own_program(red):
     assert names == {"decode_attention", "flash_attention"}
     rec = {"trace": red}
     # a layer's kernel runs once per layer and program run
-    assert readers.kernel_time(rec, "jit__decode") > 0
-    assert readers.kernel_time(rec, "jit__prefill_one") > 0
+    assert readers.kernel_time(rec, "jit__decode", "decode_attention") > 0
+    assert readers.kernel_time(rec, "jit__prefill_one", "flash_attention") > 0
+    assert readers.kernel_time(rec, "jit__decode", "flash_attention") is None
     for m, o, _, count, k in red["ops"]:
         if k:
             assert count == 2 * red["modules"][m][1]
