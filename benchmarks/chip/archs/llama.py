@@ -28,8 +28,9 @@ def served_params(cfg: Dict, mcfg, seed: int, ref):
     """The whole parameter tree in the program's layout, in the served
     dtype, in one jitted call on the device; ``ref`` is the configuration's
     reference module, whose ``layer_weights`` draws each layer."""
-    return W.served_tree(ref, cfg, seed, mcfg.n_layers, mcfg.padded_vocab,
-                         jnp.dtype(mcfg.dtype), lambda b: {
-                             "ln1": b["ln1"], "ln2": b["ln2"],
-                             "attn": {n: b[n] for n in ("wq", "wk", "wv", "wo")},
-                             "mlp": {n: b[n] for n in ("w_gate", "w_up", "w_down")}})
+    def layers(stacks):
+        b = stacks[W.ONE_KIND]
+        return {"blocks": {"ln1": b["ln1"], "ln2": b["ln2"],
+                           "attn": {n: b[n] for n in ("wq", "wk", "wv", "wo")},
+                           "mlp": {n: b[n] for n in ("w_gate", "w_up", "w_down")}}}
+    return W.served_tree(ref, cfg, seed, mcfg.padded_vocab, jnp.dtype(mcfg.dtype), layers)

@@ -10,13 +10,20 @@ generator, ``generators/<generator>.py``) and ``metrics/<metric>.py`` (a
 
 An architecture gives ``model_config(cfg)``, the program's ``ModelConfig``,
 and ``served_params(cfg, mcfg, seed, ref)``, the whole parameter tree made on
-the device.  A reference imports nothing of the program and gives
-``dims(cfg)`` (the record's ``dims``), ``layer_weights`` (the seeded draw both
-sides use), ``layer(h, w, cfg, lower)`` (one layer of the float32 pass that
-``chipbench/reference.Layerwise`` runs), and ``decode_work(dims, rows,
-sum_ctx)`` and ``prefill_work(dims, n)``: the model's operations and, per
-Pallas kernel by name, its operations and bytes; the record carries the
-module as ``ref``.
+the device by ``weights.served_tree``, which hands it each kind's layers
+stacked.  A reference imports nothing of the program and gives ``dims(cfg)``
+(the record's ``dims``); ``layer_kinds(cfg)``, the kind of each layer it
+runs in order, where its layers differ (else ``dims["L"]`` layers of one
+kind, ``weights.ONE_KIND``); per kind ``layer_shapes(cfg, kind)``,
+``layer_weights(key, li, cfg, kind, dtype)`` (the seeded draw both sides use,
+with the draw rules of any leaf that is neither a norm scale nor a
+projection) and ``layer(h, w, cfg, kind, lower)`` (one layer of the float32
+pass that ``chipbench/reference.Layerwise`` runs, built from the shared
+``attention`` and ``mamba2``); ``embedding_multiplier(cfg)`` and
+``logits_multiplier(cfg)`` where the model scales them (else 1); and
+``decode_work(dims, rows, sum_ctx)`` and ``prefill_work(dims, n)``: the
+model's operations and, per Pallas kernel by name, its operations and bytes;
+the record carries the module as ``ref``.
 """
 from __future__ import annotations
 

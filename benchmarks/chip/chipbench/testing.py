@@ -1,7 +1,9 @@
 """A copy of the benchmark with tiny configurations, a mix and a metric added
-as files only, for the CPU tests: ``tiny`` of the llama architecture, and
+as files only, for the CPU tests: ``tiny`` of the llama architecture,
 ``tiny_moe`` of a second one, ``toy_moe`` (the program's ``moe`` family),
-whose architecture and reference are new files too (``tests/toy_moe/``)."""
+and ``tiny_hybrid`` of a third, ``toy_hybrid`` (the program's ``hybrid``
+family, whose layers differ in kind), whose architectures and references
+are new files too (``tests/toy_moe/``, ``tests/toy_hybrid/``)."""
 from __future__ import annotations
 
 import json
@@ -25,8 +27,21 @@ TINY_CONFIG = {
 TINY_MOE_CONFIG = dict(TINY_CONFIG, name="tiny_moe", architecture="toy_moe",
                        reference="toy_moe", num_local_experts=4, num_experts_per_tok=2,
                        intermediate_size=64, torch_dtype="float32")
-# the second architecture's files, laid out as in the benchmark
+# granite-4.0-h's Mamba-2 keys; 5 Mamba-2 layers, the shared attention block
+# after every 2nd (applied twice), then a tail layer.  Served in bfloat16,
+# so that the control is float8: in float32 the program read a widest gap
+# of 0 on 14 seeds and the bfloat16 control 0 to 0.0051, 0 on 3 of them, so
+# no limit fails that control.  In bfloat16 the program read 0 to 0.0120
+# over 14 seeds, the float8 control 0.0343 to 0.1068, a state left out at
+# decode 0.079 or more: the limit 0.025 lies between
+TINY_HYBRID_CONFIG = dict(TINY_CONFIG, name="tiny_hybrid", architecture="toy_hybrid",
+                          reference="toy_hybrid", num_hidden_layers=5, attn_every=2,
+                          mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_d_head=16,
+                          mamba_n_heads=8, mamba_n_groups=1, mamba_chunk_size=32,
+                          check={"logit_gap_limit": 0.025})
+# the other architectures' files, laid out as in the benchmark
 TOY_MOE = os.path.join(BENCH, "tests", "toy_moe")
+TOY_HYBRID = os.path.join(BENCH, "tests", "toy_hybrid")
 
 TINY_MIX = {
     "generator": "react_agents", "agents": 6, "rounds": 16, "roster": "roster.json",
@@ -52,12 +67,14 @@ def tiny_bench(root: str, **config_overrides) -> str:
     ``tiny``."""
     dst = os.path.join(root, "bench")
     shutil.copytree(BENCH, dst, ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    shutil.copytree(TOY_MOE, dst, dirs_exist_ok=True,
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for files in (TOY_MOE, TOY_HYBRID):
+        shutil.copytree(files, dst, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     with open(os.path.join(dst, "configs", "tiny.json"), "w") as f:
         json.dump(dict(TINY_CONFIG, **config_overrides), f)
-    with open(os.path.join(dst, "configs", "tiny_moe.json"), "w") as f:
-        json.dump(TINY_MOE_CONFIG, f)
+    for cfg in (TINY_MOE_CONFIG, TINY_HYBRID_CONFIG):
+        with open(os.path.join(dst, "configs", cfg["name"] + ".json"), "w") as f:
+            json.dump(cfg, f)
     with open(os.path.join(dst, "traffic", "tiny_mix.json"), "w") as f:
         json.dump(TINY_MIX, f)
     with open(os.path.join(dst, "metrics", "turns_finished.py"), "w") as f:
@@ -68,7 +85,7 @@ def tiny_bench(root: str, **config_overrides) -> str:
                     "source": "a stand-in for CPU tests; no CPU number is a device metric"}
     with open(os.path.join(dst, "peaks.json"), "w") as f:
         json.dump(peaks, f)
-    cells = ["tiny.tiny_mix", "tiny_moe.tiny_mix"]
+    cells = ["tiny.tiny_mix", "tiny_moe.tiny_mix", "tiny_hybrid.tiny_mix"]
     bench = {
         "workloads": [{"name": c, "config": c.split(".")[0], "traffic": "tiny_mix",
                        "chips": 1} for c in cells],

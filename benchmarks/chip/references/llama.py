@@ -30,19 +30,21 @@ def dims(cfg: Dict) -> Dict[str, int]:
     }
 
 
-def layer_shapes(cfg: Dict) -> Dict[str, Tuple[int, ...]]:
-    """One layer's leaves in the order that fixes their keys."""
+def layer_shapes(cfg: Dict, kind: str) -> Dict[str, Tuple[int, ...]]:
+    """One layer's leaves in the order that fixes their keys; every layer is
+    of one kind."""
     d = dims(cfg)
     D, F = d["D"], d["F"]
     return {**R.attention_shapes(d), "w_gate": (D, F), "w_up": (D, F), "w_down": (F, D)}
 
 
-def layer_weights(seed_key, layer, cfg: Dict, dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
+def layer_weights(seed_key, layer, cfg: Dict, kind: str,
+                  dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
     """One layer's leaves; ``layer`` may be traced."""
-    return W.layer_draw(seed_key, layer, layer_shapes(cfg), dtype)
+    return W.layer_draw(seed_key, layer, layer_shapes(cfg, kind), dtype)
 
 
-def layer(h, w: Dict[str, jnp.ndarray], cfg: Dict, lower=None):
+def layer(h, w: Dict[str, jnp.ndarray], cfg: Dict, kind: str, lower=None):
     """One decoder layer over one whole sequence. h (S, D) float32."""
     h = R.attention(h, w, dims(cfg), float(cfg["rms_norm_eps"]),
                     float(cfg["rope_theta"]), lower)

@@ -58,7 +58,8 @@ def test_files_the_benchmark_has_are_unchanged(bench):
         copied = json.load(f)
     assert set(copied) - set(peaks) == {"cpu"}
     assert {k: copied[k] for k in peaks} == peaks
-    for rel in ("archs/toy_moe.py", "references/toy_moe.py", "configs/tiny_moe.json"):
+    for rel in ("archs/toy_moe.py", "references/toy_moe.py", "configs/tiny_moe.json",
+                "archs/toy_hybrid.py", "references/toy_hybrid.py", "configs/tiny_hybrid.json"):
         assert rel in _bench_files(copy) - ours
 
 

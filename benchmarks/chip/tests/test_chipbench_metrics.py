@@ -105,6 +105,34 @@ def test_counts_come_from_the_records_reference(rec, tmp_path):
     assert _metric("decode_attn_roofline")(rec) is None
 
 
+def test_a_hybrid_record_counts_each_application(rec, tmp_path):
+    """The hybrid reference counts its Mamba-2 layers per row and its shared
+    attention block at each of its applications, and the attention kernels
+    once per application, so ``mfu.*`` and the rooflines read its record."""
+    copy = os.path.dirname(testing.tiny_bench(str(tmp_path)))
+    hyb = harness.load_module(os.path.join(copy, "references", "toy_hybrid.py"),
+                              "ref_toy_hybrid_t")
+    d = hyb.dims(testing.TINY_HYBRID_CONFIG)
+    # by hand: a Mamba-2 layer meets 64*(2*128 + 2*16 + 8) + 4*(128 + 2*16) +
+    # 128*64 = 27776 weights and 5*128*16 state elements, the shared block
+    # 64*8*16 + 64*64 = 12288 weights; 5 layers, 2 applications, the head
+    # 64*256; attention 4*4*16 operations per attended position, twice
+    row = 2 * (5 * 27776 + 2 * 12288 + 64 * 256) + 5 * 5 * 128 * 16
+    assert hyb.decode_work(d, 1, 10)["model_flops"] == row + 2 * 4 * 4 * 16 * 10
+    rec["ref"], rec["dims"] = hyb, d
+    want = sum(hyb.decode_work(d, a, c)["model_flops"] for a, c in ((4, 100), (2, 50)))
+    assert _metric("mfu.decode")(rec) == pytest.approx(100 * want / (0.4 * 1e6))
+    attn = dict(d, L=2)
+    least = sum(flops.least_time(flops.decode_attn(attn, a, c), PEAKS)
+                for a, c in ((4, 100), (2, 50)))
+    assert _metric("decode_attn_roofline")(rec) == pytest.approx(100 * least / 0.02)
+    rec["trace_window"] = (0.4, 2.4)
+    least = flops.least_time(flops.flash_prefill(attn, 64), PEAKS)
+    assert _metric("flash_prefill_roofline")(rec) == pytest.approx(100 * least / 0.5)
+    want = sum(hyb.prefill_work(d, n)["model_flops"] for n in (64, 128))
+    assert _metric("mfu.prefill")(rec) == pytest.approx(100 * want / (0.4 * 1e6))
+
+
 def test_idle_share_and_no_trace(rec):
     assert _metric("device_idle_share")(rec) == pytest.approx(25.0)
     rec["trace"] = None

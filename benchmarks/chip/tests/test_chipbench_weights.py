@@ -1,8 +1,9 @@
 """The served weights and the reference's are one draw: the stacked tree made
-in one jitted call holds, layer by layer, what the reference regenerates, for
-each architecture.  And llama's weights and reference logits are pinned: the
-digests below were taken before its code moved into ``archs/llama.py`` and
-``references/llama.py``, on the CPU."""
+in one jitted call holds, layer by layer and kind by kind, what the reference
+regenerates, for each architecture.  And llama's weights and reference logits
+are pinned: the digests below were taken before its code moved into
+``archs/llama.py`` and ``references/llama.py``, on the CPU, and hold since
+references give layer kinds."""
 import hashlib
 import os
 import sys
@@ -18,7 +19,13 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(BENCH)), "src"))
 
 from chipbench import harness, reference, testing, weights  # noqa: E402
 
-CONFIGS = {"llama": testing.TINY_CONFIG, "toy_moe": testing.TINY_MOE_CONFIG}
+CONFIGS = {"llama": testing.TINY_CONFIG, "toy_moe": testing.TINY_MOE_CONFIG,
+           "toy_hybrid": testing.TINY_HYBRID_CONFIG}
+# where each kind's leaves sit in the served tree, the program's name of a
+# reference leaf where the two differ, and whether the kind is stacked (one
+# layer per index) or one copy that every application reads
+PLACES = {"toy_hybrid": {"mamba": ("blocks", {"ln_gate": "norm_w"}, True),
+                         "shared_attn": ("shared_attn", {"ln1": "ln"}, False)}}
 PIN_SEED = 2**33 + 17
 # tie_word_embeddings: (served tree, reference logits, fp8 control logits)
 PINNED = {
@@ -56,7 +63,9 @@ def _leaves(tree):
 @pytest.mark.parametrize("arch,tie", [
     pytest.param("llama", False, id="False"), pytest.param("llama", True, id="True"),
     pytest.param("toy_moe", False, id="toy_moe-False"),
-    pytest.param("toy_moe", True, id="toy_moe-True")])
+    pytest.param("toy_moe", True, id="toy_moe-True"),
+    pytest.param("toy_hybrid", False, id="toy_hybrid-False"),
+    pytest.param("toy_hybrid", True, id="toy_hybrid-True")])
 def test_served_tree_matches_the_per_layer_draw(copy_root, arch, tie):
     cfg = dict(CONFIGS[arch], tie_word_embeddings=tie)
     arch_mod, ref = _modules(copy_root, arch)
@@ -64,16 +73,23 @@ def test_served_tree_matches_the_per_layer_draw(copy_root, arch, tie):
     seed = 2**33 + 9
     p = arch_mod.served_params(cfg, mcfg, seed, ref)
     key, dtype = weights.root_key(seed), jnp.dtype(cfg["torch_dtype"])
-    got = _leaves(p["blocks"])
-    assert set(got) == set(ref.layer_shapes(cfg))
+    kinds = weights.layer_kinds(ref, cfg)
+    places = PLACES.get(arch, {weights.ONE_KIND: ("blocks", {}, True)})
+    assert set(p) == {"embed", "final_norm", "lm_head"} | {t for t, _, _ in places.values()}
     # as the reference regenerates it: one layer in a jitted call, the key
     # an argument (a constant key would let the compiler fold the draw)
-    draw = jax.jit(lambda k, li: ref.layer_weights(k, li, cfg, dtype))
-    for li in range(cfg["num_hidden_layers"]):
-        w = draw(key, li)
-        for n in w:
-            np.testing.assert_array_equal(
-                np.asarray(got[n][li]), np.asarray(w[n]).astype(np.asarray(got[n]).dtype))
+    draw = jax.jit(lambda k, li, kind: ref.layer_weights(k, li, cfg, kind, dtype),
+                   static_argnums=2)
+    for kind, (top, names, stacked) in places.items():
+        got = _leaves(p[top])
+        assert set(got) == {names.get(n, n) for n in ref.layer_shapes(cfg, kind)}
+        at = [li for li, x in enumerate(kinds) if x == kind]
+        for j, li in enumerate(at):
+            w = draw(key, li, kind)
+            for n in w:
+                a = np.asarray(got[names.get(n, n)])
+                np.testing.assert_array_equal(a[j] if stacked else a,
+                                              np.asarray(w[n]).astype(a.dtype))
     embed, head, norm = jax.jit(
         lambda k: weights.embed_weights(k, cfg, mcfg.padded_vocab, dtype))(key)
     np.testing.assert_array_equal(np.asarray(p["embed"]), np.asarray(embed))

@@ -26,10 +26,11 @@ def model_config(cfg: Dict):
 
 
 def served_params(cfg: Dict, mcfg, seed: int, ref):
-    return W.served_tree(ref, cfg, seed, mcfg.n_layers, mcfg.padded_vocab,
-                         jnp.dtype(mcfg.dtype), lambda b: {
-                             "ln1": b["ln1"], "ln2": b["ln2"],
-                             "attn": {n: b[n] for n in ("wq", "wk", "wv", "wo")},
-                             # the program routes in float32
-                             "moe": {"router": b["router"].astype(jnp.float32),
-                                     **{n: b[n] for n in ("w_gate", "w_up", "w_down")}}})
+    def layers(stacks):
+        b = stacks[W.ONE_KIND]
+        return {"blocks": {"ln1": b["ln1"], "ln2": b["ln2"],
+                           "attn": {n: b[n] for n in ("wq", "wk", "wv", "wo")},
+                           # the program routes in float32
+                           "moe": {"router": b["router"].astype(jnp.float32),
+                                   **{n: b[n] for n in ("w_gate", "w_up", "w_down")}}}}
+    return W.served_tree(ref, cfg, seed, mcfg.padded_vocab, jnp.dtype(mcfg.dtype), layers)

@@ -23,18 +23,18 @@ def dims(cfg: Dict) -> Dict[str, int]:
     }
 
 
-def layer_shapes(cfg: Dict) -> Dict[str, Tuple[int, ...]]:
+def layer_shapes(cfg: Dict, kind: str) -> Dict[str, Tuple[int, ...]]:
     d = dims(cfg)
     D, F, E = d["D"], d["F"], d["E"]
     return {**R.attention_shapes(d), "router": (D, E),
             "w_gate": (E, D, F), "w_up": (E, D, F), "w_down": (E, F, D)}
 
 
-def layer_weights(seed_key, layer, cfg: Dict, dtype=jnp.bfloat16):
-    return W.layer_draw(seed_key, layer, layer_shapes(cfg), dtype)
+def layer_weights(seed_key, layer, cfg: Dict, kind: str, dtype=jnp.bfloat16):
+    return W.layer_draw(seed_key, layer, layer_shapes(cfg, kind), dtype)
 
 
-def layer(h, w, cfg, lower=None):
+def layer(h, w, cfg, kind, lower=None):
     d, eps = dims(cfg), float(cfg["rms_norm_eps"])
     h = R.attention(h, w, d, eps, float(cfg["rope_theta"]), lower)
     x = R.rms(h, w["ln2"], eps)
